@@ -9,6 +9,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (bench_apps, bench_autoscale, bench_broker, bench_core,
                    bench_federation, bench_obs, bench_pipeline,
                    bench_preemption, bench_recovery, bench_routing,

@@ -8,7 +8,8 @@ Pipeline mirrors AlphaKnot 2.0:
    unknotted random coils; pLDDT-style quality filtering is emulated with a
    per-structure quality score),
 2. **stage 1 screen**: total writhe + average crossing number (ACN) from the
-   Gauss-linking writhe map (Pallas kernel / jnp ref) — the fast invariant,
+   Gauss-linking writhe map (the compiled Pallas kernel on TPU, the jnp
+   reference elsewhere; see ``repro.kernels.ops``) — the fast invariant,
    analogous to the paper's HOMFLY-PT screen with 200 random closures,
 3. **stage 2 knot-core localization** for candidates passing the screen: the
    paper's subchain heuristic — slide (a, b) windows over the writhe map and
@@ -130,10 +131,10 @@ def quality_score(ids: list[int]) -> np.ndarray:
 # invariants
 # ---------------------------------------------------------------------------
 
-def writhe_and_acn(coords: jax.Array, *, use_pallas: bool = False,
-                   interpret: bool = False) -> tuple[jax.Array, jax.Array, jax.Array]:
+def writhe_and_acn(coords: jax.Array
+                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """-> (total writhe (B,), ACN (B,), writhe map (B, n, n))."""
-    w = kops.writhe(coords, use_pallas=use_pallas, interpret=interpret)
+    w = kops.writhe(coords)
     wr = w.sum(axis=(1, 2)) / 2.0
     acn = jnp.abs(w).sum(axis=(1, 2)) / 2.0
     return wr, acn, w
@@ -185,7 +186,7 @@ def classify(wr: float) -> str:
 # the KSA task (paper Fig. 3 pattern)
 # ---------------------------------------------------------------------------
 
-def _screen_batch(ids: list[int], n_points: int, use_pallas: bool
+def _screen_batch(ids: list[int], n_points: int
                   ) -> tuple[list[int], list[int], dict[str, float], float]:
     """Quality-filter + writhe/ACN screen one batch of structure ids.
     -> (kept_ids, knotted_ids, writhe per knotted id, mean ACN over kept)."""
@@ -195,8 +196,7 @@ def _screen_batch(ids: list[int], n_points: int, use_pallas: bool
     if not kept_ids:
         return [], [], {}, 0.0
     coords, _ = synthesize_batch(kept_ids, n_points)
-    wr, acn, _ = writhe_and_acn(jnp.asarray(coords), use_pallas=use_pallas,
-                                interpret=use_pallas)
+    wr, acn, _ = writhe_and_acn(jnp.asarray(coords))
     wr = np.asarray(wr)
     knotted = [int(i) for i, w in zip(kept_ids, wr)
                if abs(float(w)) >= WRITHE_KNOT_THRESHOLD]
@@ -205,7 +205,7 @@ def _screen_batch(ids: list[int], n_points: int, use_pallas: bool
     return kept_ids, knotted, wr_by_id, float(np.asarray(acn).mean())
 
 
-def _localize_cores(survivors: list[int], n_points: int, use_pallas: bool,
+def _localize_cores(survivors: list[int], n_points: int,
                     check_cancel) -> dict[str, list[int]]:
     """Knot-core localization for screen survivors. Shared by the flat
     ``knot_batch`` task and the pipeline ``knot_localize`` stage so the two
@@ -221,8 +221,7 @@ def _localize_cores(survivors: list[int], n_points: int, use_pallas: bool,
     if not survivors:
         return cores
     coords, _ = synthesize_batch(survivors, n_points)
-    _, _, wmap = writhe_and_acn(jnp.asarray(coords), use_pallas=use_pallas,
-                                interpret=use_pallas)
+    _, _, wmap = writhe_and_acn(jnp.asarray(coords))
     wmap_np = np.asarray(wmap)
     for k, i in enumerate(survivors):
         check_cancel()
@@ -234,8 +233,8 @@ def _localize_cores(survivors: list[int], n_points: int, use_pallas: bool,
 
 @register_script("knot_batch")
 class KnotBatchComputing(ClusterComputing):
-    """params: batch (list of structure ids), n_points, stage2 (bool),
-    use_pallas. One task = one batch of structures (paper: 4000/batch).
+    """params: batch (list of structure ids), n_points, stage2 (bool).
+    One task = one batch of structures (paper: 4000/batch).
     Flat single-stage baseline: screen + localize fused in one task, built
     on the same helpers the pipeline stages use."""
 
@@ -243,10 +242,8 @@ class KnotBatchComputing(ClusterComputing):
         ids = list(self.params["batch"])
         n_points = int(self.params.get("n_points", 128))
         stage2 = bool(self.params.get("stage2", True))
-        use_pallas = bool(self.params.get("use_pallas", False))
 
-        kept_ids, knotted, _, mean_acn = _screen_batch(ids, n_points,
-                                                       use_pallas)
+        kept_ids, knotted, _, mean_acn = _screen_batch(ids, n_points)
         self.send_status("RUNNING", stage="screen", kept=len(kept_ids),
                          dropped=len(ids) - len(kept_ids))
         self.check_cancel()
@@ -255,8 +252,7 @@ class KnotBatchComputing(ClusterComputing):
         if stage2 and knotted:
             self.send_status("RUNNING", stage="knot_core",
                              candidates=len(knotted))
-            cores = _localize_cores(knotted, n_points, use_pallas,
-                                    self.check_cancel)
+            cores = _localize_cores(knotted, n_points, self.check_cancel)
         return {
             "processed": len(ids),
             "kept": len(kept_ids),
@@ -280,14 +276,12 @@ class KnotBatchComputing(ClusterComputing):
 @register_script("knot_screen")
 class KnotScreenComputing(ClusterComputing):
     """Stage 1 (source, fan-out): generate + quality-filter + writhe/ACN
-    screen one batch. params: batch (ids), n_points, use_pallas."""
+    screen one batch. params: batch (ids), n_points."""
 
     def run(self) -> Any:
         ids = list(self.params["batch"])
         n_points = int(self.params.get("n_points", 128))
-        use_pallas = bool(self.params.get("use_pallas", False))
-        kept_ids, knotted, wr_by_id, mean_acn = _screen_batch(
-            ids, n_points, use_pallas)
+        kept_ids, knotted, wr_by_id, mean_acn = _screen_batch(ids, n_points)
         self.send_status("RUNNING", stage="screen", kept=len(kept_ids),
                          survivors=len(knotted))
         self.check_cancel()
@@ -311,9 +305,7 @@ class KnotLocalizeComputing(ClusterComputing):
         upstream = dict(self.params.get("upstream") or {})
         survivors = [int(i) for i in upstream.get("knotted", [])]
         n_points = int(self.params.get("n_points", 128))
-        use_pallas = bool(self.params.get("use_pallas", False))
-        cores = _localize_cores(survivors, n_points, use_pallas,
-                                self.check_cancel)
+        cores = _localize_cores(survivors, n_points, self.check_cancel)
         return {"candidates": len(survivors), "cores": cores}
 
 
@@ -352,7 +344,6 @@ def _no_survivors(screen_result: Any) -> bool:
 
 
 def knots_pipeline(batch_size: int = 12, *, n_points: int = 96,
-                   use_pallas: bool = False,
                    max_in_flight: int | None = None,
                    max_attempts: int = 4,
                    task_timeout_s: float | None = None,
@@ -381,9 +372,14 @@ def knots_pipeline(batch_size: int = 12, *, n_points: int = 96,
     from repro.core import Resources
 
     retry = RetryPolicy(max_attempts=max_attempts, timeout_s=task_timeout_s)
-    common = {"n_points": n_points, "use_pallas": use_pallas}
-    localize_res = (Resources(cpus=1, gpus=1) if gpu_localize
-                    else Resources(cpus=2))
+    common = {"n_points": n_points}
+    # localization pulls a whole batch's f32 writhe map to the host: at the
+    # paper's 4000 structures x 256 points that alone is ~1 GB, past the
+    # 1 GB default request that memory policing enforces.
+    map_mb = math.ceil(batch_size * (n_points - 1) ** 2 * 4 / 2**20)
+    mem_mb = max(Resources().mem_mb, 512 + map_mb)
+    localize_res = (Resources(cpus=1, gpus=1, mem_mb=mem_mb) if gpu_localize
+                    else Resources(cpus=2, mem_mb=mem_mb))
     localize_res.site = localize_site
     return PipelineSpec("alphaknot", [
         Stage("screen", "knot_screen", fan_out=batch_size, params=common,

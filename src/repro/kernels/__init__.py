@@ -1,5 +1,6 @@
-"""Pallas TPU kernels for the perf-critical hot spots, each with a jit'd
-dispatch wrapper (ops.py) and a pure-jnp oracle (ref.py):
+"""Pallas TPU kernels for the perf-critical hot spots, each with a pure-jnp
+oracle (ref.py); ops.py and flash_decode.py dispatch by platform (compiled
+Pallas on TPU):
 
 * ``flash_attention`` — causal/sliding-window GQA, online softmax, VMEM
   block tiling with causal/window block skipping;

@@ -30,11 +30,16 @@ Three callables share the contract:
 * :func:`decode_attention` — backend dispatch between the two.
 
 :func:`flash_decode_paged` / :func:`decode_attention_paged` are the paged
-variants: KV lives in a physical page pool ``(P, page_size, K, D)`` and a
+variants: KV lives in a physical page pool ``(P, K, page_size, D)`` and a
 per-slot page table ``(B, pages_per_slot)`` (-1 = unbound) is scalar-
 prefetched so the k/v ``index_map`` gathers pages directly — no logical
 cache is ever materialized, and work scales with *bound pages*, not
 ``max_len``.
+
+Every block's last two dims are whole array dims or (8, 128)-aligned, and
+the online-softmax stats are 2-D ``(G, 1)`` VMEM scratch, so both kernels
+compile for the TPU (``tests/test_tpu_compile.py`` checks this against a
+described v5e). On TPU the dispatchers always run them compiled.
 """
 from __future__ import annotations
 
@@ -46,11 +51,51 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import on_tpu
+
 NEG_INF = -1e30
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+# ---------------------------------------------------------------------------
+# online-softmax pieces shared by the dense and paged kernels
+# ---------------------------------------------------------------------------
+
+
+def _init_stats(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale):
+    """Fold one kv block into the running (m, l, acc). q: (G, Dk);
+    k: (bk, Dk); v: (bk, Dv); mask: (1, bk), at least one entry live."""
+    q = q.astype(jnp.float32)
+    k = k.astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask, s, NEG_INF)                  # (G, bk)
+    m_prev = m_scr[...]                               # (G, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
+        p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+
+
+def _finalize(o_ref, l_scr, acc_scr):
+    # l == 0 (fully-masked slot, e.g. an empty lane) yields zeros, the
+    # same convention as the chunked reference.
+    o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-37)
+                   ).astype(o_ref.dtype)
+
+
+def _stat_scratch(g: int, dv: int) -> list:
+    return [pltpu.VMEM((g, 1), jnp.float32),      # running max
+            pltpu.VMEM((g, 1), jnp.float32),      # running sum
+            pltpu.VMEM((g, dv), jnp.float32)]     # output accumulator
 
 
 # ---------------------------------------------------------------------------
@@ -66,40 +111,22 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_stats(m_scr, l_scr, acc_scr)
 
     qp = qpos_ref[bi]
-    kp = kpos_ref[...]                       # (1, block_k) int32
+    kp = kpos_ref[0]                         # (1, block_k) int32
     mask = (kp >= 0) & (kp <= qp)
     if window is not None:
         mask &= kp > qp - window
 
     @pl.when(jnp.any(mask))
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, Dk)
-        k = k_ref[0, 0].astype(jnp.float32)  # (block_k, Dk)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask, s, NEG_INF)      # (G, block_k) via (1, bk) bcast
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        v = v_ref[0, 0].astype(jnp.float32)  # (block_k, Dv)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        _attend_block(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], mask,
+                      m_scr, l_scr, acc_scr, scale)
 
     @pl.when(ki == n_k - 1)
-    def _finalize():
-        # l == 0 (fully-masked slot, e.g. an empty lane) yields zeros, the
-        # same convention as the chunked reference.
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-37)[:, None]
-                       ).astype(o_ref.dtype)
+    def _out():
+        _finalize(o_ref, l_scr, acc_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k", "scale",
@@ -150,21 +177,18 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                          lambda bi, hi, ki, qp: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, block_k, dv),
                          lambda bi, hi, ki, qp: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, ki, qp: (bi, ki)),
+            pl.BlockSpec((1, 1, block_k), lambda bi, hi, ki, qp: (bi, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda bi, hi, ki, qp: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),       # running max
-            pltpu.VMEM((g,), jnp.float32),       # running sum
-            pltpu.VMEM((g, dv), jnp.float32),    # output accumulator
-        ],
+        scratch_shapes=_stat_scratch(g, dv),
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dv), q.dtype),
         interpret=interpret,
-    )(q_positions.astype(jnp.int32), qt, kt, vt, k_positions)
+    )(q_positions.astype(jnp.int32), qt, kt, vt,
+      k_positions.astype(jnp.int32)[:, None, :])
     return out.reshape(b, 1, h, dv)
 
 
@@ -244,12 +268,13 @@ def flash_decode_xla(q: jax.Array, k: jax.Array, v: jax.Array,
 def decode_attention(q, k, v, q_positions, k_positions=None, *,
                      window=None, block_k=128, interpret=False,
                      bounded=True):
-    """Backend dispatch: Pallas kernel on TPU (or under ``interpret=True``
-    for parity tests), split-KV XLA lowering elsewhere."""
-    if _on_tpu() or interpret:
+    """Backend dispatch: the compiled Pallas kernel on TPU, whatever
+    ``interpret`` says; elsewhere the kernel in interpret mode when a test
+    asks for it, and otherwise the split-KV XLA lowering."""
+    if on_tpu() or interpret:
         return flash_decode(q, k, v, q_positions, k_positions,
                             window=window, block_k=block_k,
-                            interpret=interpret and not _on_tpu())
+                            interpret=not on_tpu())
     return flash_decode_xla(q, k, v, q_positions, k_positions,
                             window=window, block_k=block_k, bounded=bounded)
 
@@ -267,42 +292,28 @@ def _paged_kernel(qpos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_stats(m_scr, l_scr, acc_scr)
 
     qp = qpos_ref[bi]
     page = table_ref[bi, pi]
     # pages are bound in logical order, so slot offsets map to positions
     # pi*page_size + offset directly; no per-slot position array needed.
-    kp = pi * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)
-    mask = (kp <= qp) & (page >= 0)
+    first = pi * page_size
+    kp = first + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+    mask = kp <= qp
+    live = (page >= 0) & (first <= qp)
     if window is not None:
         mask &= kp > qp - window
+        live &= first + page_size - 1 > qp - window
 
-    @pl.when(jnp.any(mask))
+    @pl.when(live)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)           # (G, Dk)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # (page_size, Dk)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
-        v = v_ref[0, :, 0].astype(jnp.float32)        # (page_size, Dv)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        _attend_block(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], mask,
+                      m_scr, l_scr, acc_scr, scale)
 
     @pl.when(pi == n_pages - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-37)[:, None]
-                       ).astype(o_ref.dtype)
+    def _out():
+        _finalize(o_ref, l_scr, acc_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
@@ -310,7 +321,7 @@ def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                        q_positions: jax.Array, page_table: jax.Array, *,
                        window: int | None = None, scale: float | None = None,
                        interpret: bool = False) -> jax.Array:
-    """q: (B, 1, H, Dk); pool_k: (P, page_size, K, Dk); pool_v likewise with
+    """q: (B, 1, H, Dk); pool_k: (P, K, page_size, Dk); pool_v likewise with
     Dv; page_table: (B, pages_per_slot) int32, -1 = unbound (page 0 is the
     allocator's reserved trash page). -> (B, 1, H, Dv).
 
@@ -320,7 +331,7 @@ def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     """
     b, sq, h, dk = q.shape
     assert sq == 1
-    _, page_size, kh, _ = pool_k.shape
+    _, kh, page_size, _ = pool_k.shape
     dv = pool_v.shape[-1]
     g = h // kh
     n_pages = page_table.shape[1]
@@ -332,7 +343,7 @@ def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                                page_size=page_size, n_pages=n_pages)
 
     def kv_map(bi, hi, pi, qp, table):
-        return (jnp.maximum(table[bi, pi], 0), 0, hi, 0)
+        return (jnp.maximum(table[bi, pi], 0), hi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -340,16 +351,12 @@ def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, g, dk),
                          lambda bi, hi, pi, qp, tb: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dk), kv_map),
-            pl.BlockSpec((1, page_size, 1, dv), kv_map),
+            pl.BlockSpec((1, 1, page_size, dk), kv_map),
+            pl.BlockSpec((1, 1, page_size, dv), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda bi, hi, pi, qp, tb: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
+        scratch_shapes=_stat_scratch(g, dv),
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
@@ -372,7 +379,7 @@ def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
     pages (occupancy), never materializing the logical cache."""
     b, sq, h, dk = q.shape
     assert sq == 1
-    _, page_size, kh, _ = pool_k.shape
+    _, kh, page_size, _ = pool_k.shape
     dv = pool_v.shape[-1]
     g = h // kh
     n_pages = page_table.shape[1]
@@ -389,13 +396,13 @@ def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
     def body(i, carry):
         m_run, l_run, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(table, i, 1, axis=1)[:, 0]
-        kc = pool_k[jnp.maximum(pages, 0)]     # (B, page_size, K, Dk)
+        kc = pool_k[jnp.maximum(pages, 0)]     # (B, K, page_size, Dk)
         vc = pool_v[jnp.maximum(pages, 0)]
         kp = i * page_size + jnp.arange(page_size, dtype=jnp.int32)[None, :]
         mask = (pages >= 0)[:, None] & (kp <= qp[:, None])
         if window is not None:
             mask &= kp > qp[:, None] - window
-        sc = jnp.einsum("bkgd,bckd->bkgc", qh, kc.astype(jnp.float32),
+        sc = jnp.einsum("bkgd,bkcd->bkgc", qh, kc.astype(jnp.float32),
                         preferred_element_type=jnp.float32) * scale
         sc = jnp.where(mask[:, None, None, :], sc, NEG_INF)
         m_new = jnp.maximum(m_run, sc.max(axis=-1))
@@ -405,7 +412,7 @@ def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
                       jnp.exp(sc - m_new[..., None]), 0.0)
         corr = jnp.exp(m_run - m_new)
         l_new = l_run * corr + p.sum(axis=-1)
-        pv = jnp.einsum("bkgc,bckd->bkgd", p, vc.astype(jnp.float32),
+        pv = jnp.einsum("bkgc,bkcd->bkgd", p, vc.astype(jnp.float32),
                         preferred_element_type=jnp.float32)
         return m_new, l_new, acc * corr[..., None] + pv
 
@@ -419,10 +426,10 @@ def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
 
 def decode_attention_paged(q, pool_k, pool_v, q_positions, page_table, *,
                            window=None, interpret=False):
-    """Backend dispatch for the paged cache path."""
-    if _on_tpu() or interpret:
+    """Backend dispatch for the paged cache path, by the same rule as
+    :func:`decode_attention`."""
+    if on_tpu() or interpret:
         return flash_decode_paged(q, pool_k, pool_v, q_positions, page_table,
-                                  window=window,
-                                  interpret=interpret and not _on_tpu())
+                                  window=window, interpret=not on_tpu())
     return flash_decode_paged_xla(q, pool_k, pool_v, q_positions, page_table,
                                   window=window)

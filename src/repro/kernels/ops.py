@@ -1,38 +1,24 @@
-"""jit'd dispatch wrappers: Pallas kernel on TPU (or under interpret=True),
-pure-jnp reference elsewhere. The model stack calls these, so flipping
-``ModelConfig.use_pallas`` swaps the hot paths in one place."""
+"""Kernel dispatch by platform.
+
+On TPU a kernel asked for runs as compiled Pallas: there is no interpret
+mode and no swap to the reference there, so a kernel that the TPU compiler
+refuses raises. On other backends the pure-jnp reference runs, or the
+Pallas kernel in interpret mode where a test asks for it with
+``interpret=True``.
+"""
 from __future__ import annotations
 
 import jax
 
 from . import ref as _ref
-from .flash_attention import flash_attention
-from .ssd import ssd_scan
 from .writhe import writhe_map
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def attention(q, k, v, *, causal=True, window=None, use_pallas=False,
-              interpret=False):
-    if use_pallas and (_on_tpu() or interpret):
-        return flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=interpret or not _on_tpu())
-    return _ref.attention_ref(q, k, v, causal=causal, window=window)
-
-
-def ssd(x, dt, a, bmat, cmat, *, chunk=256, use_pallas=False,
-        interpret=False):
-    if use_pallas and (_on_tpu() or interpret):
-        return ssd_scan(x, dt, a, bmat, cmat, chunk=chunk,
-                        interpret=interpret or not _on_tpu())
-    return _ref.ssd_ref(x, dt, a, bmat, cmat, chunk=chunk)
-
-
-def writhe(coords, *, block=128, use_pallas=False, interpret=False):
-    if use_pallas and (_on_tpu() or interpret):
-        return writhe_map(coords, block=block,
-                          interpret=interpret or not _on_tpu())
+def writhe(coords, *, block=128, interpret=False):
+    if on_tpu() or interpret:
+        return writhe_map(coords, block=block, interpret=not on_tpu())
     return _ref.writhe_map_ref(coords)

@@ -37,6 +37,23 @@ def _norm(a, eps):
     return (a[0] / n, a[1] / n, a[2] / n)
 
 
+def _asin(x):
+    """arcsin on [-1, 1] from sqrt and a polynomial (Cephes ``asinf``, peak
+    relative error ~2.5e-7 in f32): Mosaic has no lowering for ``asin``.
+    |x| <= 1/2 uses the series in x²; above it, the half-angle identity
+    asin(a) = π/2 - 2·asin(sqrt((1-a)/2)) keeps the argument small."""
+    a = jnp.abs(x)
+    big = a > 0.5
+    z = jnp.where(big, 0.5 * (1.0 - a), a * a)
+    r = jnp.where(big, jnp.sqrt(z), a)
+    poly = ((((4.2163199048e-2 * z + 2.4181311049e-2) * z
+              + 4.5470025998e-2) * z + 7.4953002686e-2) * z
+            + 1.6666752422e-1)
+    y = r + r * z * poly
+    y = jnp.where(big, 0.5 * jnp.pi - 2.0 * y, y)
+    return jnp.where(x < 0, -y, y)
+
+
 def _writhe_block(p1, p2, q1, q2, eps=1e-12):
     """Signed pair contribution for segment blocks.
     p1/p2: tuple of 3 arrays (bi, 1); q1/q2: (1, bj). Returns (bi, bj)."""
@@ -50,7 +67,7 @@ def _writhe_block(p1, p2, q1, q2, eps=1e-12):
     n4 = _norm(_cross(r23, r13), eps)
 
     def asin_clip(x):
-        return jnp.arcsin(jnp.clip(x, -1.0, 1.0))
+        return _asin(jnp.clip(x, -1.0, 1.0))
 
     omega = (asin_clip(_dot(n1, n2)) + asin_clip(_dot(n2, n3)) +
              asin_clip(_dot(n3, n4)) + asin_clip(_dot(n4, n1)))
@@ -61,12 +78,16 @@ def _writhe_block(p1, p2, q1, q2, eps=1e-12):
 
 
 def _writhe_kernel(s1_ref, s2_ref, t1_ref, t2_ref, o_ref, *, block: int):
+    """s1/s2: (1, 3, block, 1) segment endpoints of the i block as columns;
+    t1/t2: (1, 3, block) endpoints of the j block as rows. Carrying each
+    coordinate on its own leading index keeps every block (8, 128)-tiled,
+    so the pairwise math is plain (block, 1) x (1, block) broadcasting."""
     bi = pl.program_id(1)
     bj = pl.program_id(2)
-    p1 = tuple(s1_ref[0, :, k][:, None] for k in range(3))  # (bi, 1)
-    p2 = tuple(s2_ref[0, :, k][:, None] for k in range(3))
-    q1 = tuple(t1_ref[0, :, k][None, :] for k in range(3))  # (1, bj)
-    q2 = tuple(t2_ref[0, :, k][None, :] for k in range(3))
+    p1 = tuple(s1_ref[0, k] for k in range(3))            # (bi, 1)
+    p2 = tuple(s2_ref[0, k] for k in range(3))
+    q1 = tuple(t1_ref[0, k:k + 1, :] for k in range(3))   # (1, bj)
+    q2 = tuple(t2_ref[0, k:k + 1, :] for k in range(3))
     w = _writhe_block(p1, p2, q1, q2)
     # adjacent/identical segments have no well-defined crossing: zero the
     # |i - j| <= 1 band.
@@ -81,7 +102,8 @@ def writhe_map(coords: jax.Array, *, block: int = 128,
                interpret: bool = False) -> jax.Array:
     """coords: (B, n_points, 3) backbone (e.g. Cα trace) ->
     writhe map (B, n_seg, n_seg) with n_seg = n_points - 1 (padded to a
-    multiple of ``block``; pad segments are degenerate and contribute 0)."""
+    multiple of ``block``; pad segments are degenerate and contribute 0).
+    Compiled for TPU, ``block`` must be a multiple of 128."""
     b, npts, _ = coords.shape
     nseg = npts - 1
     s1 = coords[:, :-1]
@@ -94,18 +116,17 @@ def writhe_map(coords: jax.Array, *, block: int = 128,
         s2 = jnp.concatenate([s2, jnp.repeat(last, pad, 1)], axis=1)
     n = s1.shape[1]
     nb = n // block
+    r1 = s1.transpose(0, 2, 1)                  # (B, 3, n) rows
+    r2 = s2.transpose(0, 2, 1)
+    col_spec = pl.BlockSpec((1, 3, block, 1), lambda bi, i, j: (bi, 0, i, 0))
+    row_spec = pl.BlockSpec((1, 3, block), lambda bi, i, j: (bi, 0, j))
     out = pl.pallas_call(
         functools.partial(_writhe_kernel, block=block),
         grid=(b, nb, nb),
-        in_specs=[
-            pl.BlockSpec((1, block, 3), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, block, 3), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, block, 3), lambda bi, i, j: (bi, j, 0)),
-            pl.BlockSpec((1, block, 3), lambda bi, i, j: (bi, j, 0)),
-        ],
+        in_specs=[col_spec, col_spec, row_spec, row_spec],
         out_specs=pl.BlockSpec((1, block, block),
                                lambda bi, i, j: (bi, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n, n), jnp.float32),
         interpret=interpret,
-    )(s1, s2, s1, s2)
+    )(r1[..., None], r2[..., None], r1, r2)
     return out[:, :nseg, :nseg]

@@ -1,7 +1,10 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines, before ANY jax import: jax locks the device
-# count on first init. 512 placeholder host devices back the production mesh.
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512"]))
+# ^ MUST run before ANY jax import: jax locks the device count on first
+# init. 512 placeholder host devices back the production mesh; flags the
+# caller already set are kept.
 
 """Multi-pod dry-run: lower + compile every (architecture × shape) cell on
 the production meshes and extract memory / cost / collective analyses.
@@ -120,8 +123,6 @@ def _compile_cell(arch, shape, multi_pod, overrides, costing_periods=None):
 
 def _costs_of(compiled) -> dict:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # jax < 0.6 returns [dict] per computation
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     return {
         "flops": float(cost.get("flops", 0.0)),

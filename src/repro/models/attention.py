@@ -212,7 +212,7 @@ def attention_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
 
     ``cache``: {"k": (B, S_cache, K, Dh), "v": ...}. If ``S_cache == window``
     for a local layer, the cache is treated as a **ring buffer**. A paged
-    cache instead holds {"pool_k": (P, page_size, K, Dh), "pool_v": ...}
+    cache instead holds {"pool_k": (P, K, page_size, Dh), "pool_v": ...}
     and requires ``pages``: the (B, pages_per_slot) int32 page table
     (-1 = unbound; page 0 is the allocator's trash page).
     ``cache_index``: scalar int32 — count of tokens already cached.
@@ -336,13 +336,13 @@ def _paged_decode(cfg: ModelConfig, q: jax.Array, k: jax.Array,
     b = q.shape[0]
     dt = q.dtype
     cdt = cache["pool_k"].dtype
-    page_size = cache["pool_k"].shape[1]
+    page_size = cache["pool_k"].shape[2]
     rows = jnp.arange(b, dtype=jnp.int32)
     page = pages[rows, positions // page_size]
     page = jnp.maximum(page, 0)
     off = positions % page_size
-    ck = cache["pool_k"].at[page, off].set(k[:, 0].astype(cdt))
-    cv = cache["pool_v"].at[page, off].set(v[:, 0].astype(cdt))
+    ck = cache["pool_k"].at[page, :, off].set(k[:, 0].astype(cdt))
+    cv = cache["pool_v"].at[page, :, off].set(v[:, 0].astype(cdt))
     new_cache = {"pool_k": ck, "pool_v": cv}
     if cfg.decode_kernel == "flash":
         from repro.kernels.flash_decode import decode_attention_paged
@@ -352,10 +352,13 @@ def _paged_decode(cfg: ModelConfig, q: jax.Array, k: jax.Array,
         return new_cache, out
     n_pages = pages.shape[1]
     tbl = jnp.maximum(pages, 0)
-    kh, dk = ck.shape[2], ck.shape[3]
+    kh, dk = ck.shape[1], ck.shape[3]
     dv = cv.shape[3]
-    k_lin = ck[tbl].reshape(b, n_pages * page_size, kh, dk)
-    v_lin = cv[tbl].reshape(b, n_pages * page_size, kh, dv)
+    # (B, n_pages, K, page_size, D) -> logical (B, n_pages*page_size, K, D)
+    k_lin = ck[tbl].transpose(0, 1, 3, 2, 4).reshape(
+        b, n_pages * page_size, kh, dk)
+    v_lin = cv[tbl].transpose(0, 1, 3, 2, 4).reshape(
+        b, n_pages * page_size, kh, dv)
     kp = (jnp.arange(n_pages, dtype=jnp.int32)[:, None] * page_size +
           jnp.arange(page_size, dtype=jnp.int32)[None, :])
     kp = jnp.where(pages[:, :, None] >= 0, kp[None], -1)

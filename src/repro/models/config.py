@@ -96,12 +96,11 @@ class ModelConfig:
     dtype: str = "bfloat16"                 # activations/weights compute dtype
     # attention implementation knobs
     kv_chunk: int = 1024                    # chunked-softmax KV block
-    use_pallas: bool = False                # TPU kernels (tests use interpret)
     decode_kernel: str = "chunked"          # serving decode: "chunked"
                                             # (reference) | "flash"
                                             # (split-KV flash-decode)
-    kernel_interpret: bool = False          # Pallas interpret mode (CPU
-                                            # parity tests)
+    kernel_interpret: bool = False          # Pallas interpret mode on CPU
+                                            # (tests); TPU always compiles
     logit_dtype: str = "float32"
     score_dtype: str = "float32"            # attention score/probability dtype
                                             # (bf16 halves the S×chunk buffers)

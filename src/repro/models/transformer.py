@@ -299,9 +299,9 @@ def _paged_cache_for(cfg: ModelConfig, kind: str, batch: int, max_len: int,
             # ring buffers are already O(window); keep them dense.
             return init_kv_cache(cfg, kind, batch, max_len, dtype)
         return {
-            "pool_k": jnp.zeros((n_pages, page_size, cfg.n_kv_heads,
+            "pool_k": jnp.zeros((n_pages, cfg.n_kv_heads, page_size,
                                  cfg.head_dim), dtype),
-            "pool_v": jnp.zeros((n_pages, page_size, cfg.n_kv_heads,
+            "pool_v": jnp.zeros((n_pages, cfg.n_kv_heads, page_size,
                                  cfg.head_dim), dtype),
         }
     return _cache_for(cfg, kind, batch, max_len, dtype)
@@ -311,7 +311,7 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int, dtype, *,
                       page_size: int = 64,
                       n_pages: int | None = None) -> dict:
     """Decode cache pytree with paged KV for the full-context attention
-    layers: physical pools ``(n_pages, page_size, K, Dh)`` indexed through
+    layers: physical pools ``(n_pages, K, page_size, Dh)`` indexed through
     the page table that :func:`forward` takes as ``pages``. Ring (local)
     and recurrent (ssd/rglru) caches keep their dense layout — they are
     already O(window) / O(1) per slot. Page 0 is reserved as the trash page

@@ -82,6 +82,11 @@ class ServeEngine:
     ``step()`` must be driven by a single thread (the replica driver);
     ``add_request`` / ``evict`` may be called concurrently from any thread
     and only touch host state under the admission lock.
+
+    ``device`` commits the engine's parameters and caches to one device, so
+    its steps run there (one replica per chip); by default they stay on
+    JAX's default device. After each step, ``last_logits`` holds that
+    step's (n_slots, padded_vocab) logits on the device.
     """
 
     def __init__(self, cfg: ModelConfig, params: Any, *, n_slots: int = 4,
@@ -92,7 +97,7 @@ class ServeEngine:
                  kernel_interpret: bool | None = None,
                  admission: str = "lazy",
                  registry: Any = None, replica: str = "0",
-                 step_latency_s: float = 0.0):
+                 step_latency_s: float = 0.0, device: Any = None):
         if decode_kernel is not None:
             cfg = cfg.with_(decode_kernel=decode_kernel)
         if kernel_interpret is not None:
@@ -108,7 +113,9 @@ class ServeEngine:
                              "with paged=True; use the default lazy "
                              "admission for paged caches")
         self.cfg = cfg
-        self.params = params
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.n_slots = n_slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -117,19 +124,25 @@ class ServeEngine:
         self.replica = replica
         self.step_latency_s = step_latency_s
         dt = jnp.dtype(cfg.dtype)
-        if paged:
-            pages_per_slot, pool_pages = paged_layout(max_len, page_size,
-                                                      n_slots, n_pages)
-            self.caches = init_paged_caches(cfg, n_slots, max_len, dt,
-                                            page_size=page_size,
-                                            n_pages=pool_pages)
-            self.allocator: PageAllocator | None = PageAllocator(
-                pool_pages, page_size, n_slots, pages_per_slot)
-            self._serve = jax.jit(make_serve_step(cfg, paged=True))
-        else:
-            self.caches = init_caches(cfg, n_slots, max_len, dt)
-            self.allocator = None
-            self._serve = jax.jit(make_serve_step(cfg))
+        # build the caches where they will live: a pool made on the default
+        # device first would briefly hold a second copy there
+        with jax.default_device(device):
+            if paged:
+                pages_per_slot, pool_pages = paged_layout(
+                    max_len, page_size, n_slots, n_pages)
+                self.caches = init_paged_caches(cfg, n_slots, max_len, dt,
+                                                page_size=page_size,
+                                                n_pages=pool_pages)
+                self.allocator: PageAllocator | None = PageAllocator(
+                    pool_pages, page_size, n_slots, pages_per_slot)
+                self._serve = jax.jit(make_serve_step(cfg, paged=True))
+            else:
+                self.caches = init_caches(cfg, n_slots, max_len, dt)
+                self.allocator = None
+                self._serve = jax.jit(make_serve_step(cfg))
+        if device is not None:
+            self.caches = jax.device_put(self.caches, device)
+        self.last_logits: jax.Array | None = None
         self.slots = [_Slot() for _ in range(n_slots)]
         self._lock = threading.Lock()
         self._step_guard = threading.Lock()
@@ -355,6 +368,7 @@ class ServeEngine:
         else:
             logits, next_ids, new_caches = self._serve(
                 self.params, jnp.asarray(col), caches, jnp.asarray(pos))
+        self.last_logits = logits
         next_ids = np.asarray(next_ids)  # device sync, still outside the lock
         if self.step_latency_s:
             # benchmark knob: emulate an accelerator-bound step on hosts

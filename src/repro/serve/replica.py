@@ -35,6 +35,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+import jax
+
 from repro.core import ClusterComputing, Resources, register_script
 from repro.core.scheduling import ResourceProfile
 
@@ -76,7 +78,11 @@ class PendingRequest:
 
 
 class ServeReplicaSet:
-    """N serving replicas, one router, exact request accounting."""
+    """N serving replicas, one router, exact request accounting.
+
+    Replica ``i`` places its params and caches on
+    ``jax.devices()[i % len(jax.devices())]``, so on a multi-chip host each
+    replica serves from its own chip."""
 
     def __init__(self, cfg, params, *, n_replicas: int = 2,
                  engine_kw: dict | None = None,
@@ -87,8 +93,10 @@ class ServeReplicaSet:
         if on_violation not in ("queue", "shed", "spill"):
             raise ValueError(f"unknown on_violation {on_violation!r}")
         kw = dict(engine_kw or {})
+        devices = jax.devices()
         self.engines = [ServeEngine(cfg, params, replica=f"r{i}",
-                                    registry=registry, **kw)
+                                    registry=registry,
+                                    device=devices[i % len(devices)], **kw)
                         for i in range(n_replicas)]
         self.n_replicas = n_replicas
         self.ttft_slo = ttft_slo

@@ -29,10 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # promoted out of experimental in jax 0.6
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.moe import moe_capacity, shared_expert

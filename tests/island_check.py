@@ -1,75 +1,18 @@
 """Subprocess helper: verifies the sharded program (GSPMD + MoE island +
 vocab-parallel CE) matches the single-device path numerically on an 8-device
-host mesh. Run via tests/test_distributed.py; exits nonzero on mismatch."""
+host mesh — the loss and the updated parameters of one training step. Run
+via tests/test_distributed.py; exits nonzero on mismatch."""
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import sys
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+from repro.launch.mesh import make_smoke_mesh
+from repro.sharding.parity import train_step_parity
 
-from repro.configs import smoke_config
-from repro.models import init_params, model_spec
-from repro.optim import OptimizerConfig
-from repro.sharding import DistContext, state_axes
-from repro.train import init_train_state, make_train_step
-from repro.train.step import train_state_shapes
-
-
-def check_arch(arch: str, mesh) -> float:
-    cfg = smoke_config(arch)
-    ocfg = OptimizerConfig(lr=1e-2, warmup_steps=0, schedule="constant",
-                           weight_decay=0.0)
-    rng = np.random.RandomState(0)
-    b, s = 4, 32
-    if cfg.frontend is not None and cfg.frontend.kind == "audio_frames":
-        batch = {"embeds": jnp.asarray(rng.randn(b, s, cfg.frontend.input_dim),
-                                       jnp.float32),
-                 "labels": jnp.asarray(rng.randint(0, cfg.vocab_size, (b, s)),
-                                       jnp.int32)}
-    elif cfg.frontend is not None:
-        batch = {"embeds": jnp.asarray(
-                     rng.randn(b, cfg.frontend.n_positions,
-                               cfg.frontend.input_dim), jnp.float32),
-                 "tokens": jnp.asarray(rng.randint(0, cfg.vocab_size, (b, s)),
-                                       jnp.int32),
-                 "labels": jnp.asarray(rng.randint(0, cfg.vocab_size, (b, s)),
-                                       jnp.int32)}
-    else:
-        batch = {"tokens": jnp.asarray(rng.randint(0, cfg.vocab_size, (b, s)),
-                                       jnp.int32),
-                 "labels": jnp.asarray(rng.randint(0, cfg.vocab_size, (b, s)),
-                                       jnp.int32)}
-
-    state = init_train_state(cfg, ocfg, jax.random.PRNGKey(0))
-
-    # single-device reference
-    step_ref = jax.jit(make_train_step(cfg, ocfg))
-    _, m_ref = step_ref(jax.tree.map(jnp.copy, state), batch)
-
-    # sharded
-    dist = DistContext(mesh)
-    st_axes = state_axes(cfg, ocfg)
-    state_sh = dist.param_shardings(train_state_shapes(cfg, ocfg), st_axes)
-    batch_sh = {k: dist.named(dist.batch_pspec(v.ndim, b))
-                for k, v in batch.items()}
-    state_d = jax.device_put(state, state_sh)
-    batch_d = jax.device_put(batch, batch_sh)
-    with mesh:
-        step_sh = jax.jit(make_train_step(cfg, ocfg, dist=dist),
-                          in_shardings=(state_sh, batch_sh),
-                          out_shardings=(state_sh, None))
-        new_state, m_sh = step_sh(state_d, batch_d)
-        jax.block_until_ready(new_state.params)
-
-    err = abs(float(m_ref["loss"]) - float(m_sh["loss"]))
-    rel = err / max(abs(float(m_ref["loss"])), 1e-9)
-    print(f"{arch}: ref={float(m_ref['loss']):.6f} "
-          f"sharded={float(m_sh['loss']):.6f} rel={rel:.2e}", flush=True)
-    return rel
+LOSS_RTOL = 2e-3
+UPDATE_RTOL = 1e-2
 
 
 def main():
@@ -77,15 +20,21 @@ def main():
                              "mamba2_130m", "recurrentgemma_2b",
                              "deepseek_v3_671b", "hubert_xlarge",
                              "internvl2_1b", "stablelm_1_6b"]
-    from repro.launch.mesh import make_smoke_mesh
     mesh = make_smoke_mesh(n_data=2, n_model=2, pods=2)  # (2,2,2) = 8 devices
-    worst = 0.0
+    worst_loss = worst_update = 0.0
     for a in archs:
-        worst = max(worst, check_arch(a, mesh))
-    if worst > 2e-3:
-        print(f"FAIL: worst rel err {worst}")
+        r = train_step_parity(a, mesh)
+        print(f"{a}: ref={r['loss_ref']:.6f} sharded={r['loss_sharded']:.6f} "
+              f"loss_rel={r['loss_rel']:.2e} update_rel={r['update_rel']:.2e}",
+              flush=True)
+        worst_loss = max(worst_loss, r["loss_rel"])
+        worst_update = max(worst_update, r["update_rel"])
+    if worst_loss > LOSS_RTOL or worst_update > UPDATE_RTOL:
+        print(f"FAIL: worst loss rel err {worst_loss}, "
+              f"worst update rel err {worst_update}")
         sys.exit(1)
-    print(f"OK worst rel err {worst:.2e}")
+    print(f"OK worst loss rel err {worst_loss:.2e}, "
+          f"update rel err {worst_update:.2e}")
 
 
 if __name__ == "__main__":

@@ -32,11 +32,11 @@ def test_sharded_matches_single_device_moe():
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
 
 
-def test_dryrun_smoke_cell():
+def test_dryrun_smoke_cell(tmp_path):
     """One real dry-run cell end-to-end (small arch) on the production mesh
     machinery — exercises dryrun.py exactly as the full matrix does."""
     r = _run(["-m", "repro.launch.dryrun", "--arch", "mamba2_130m",
               "--shape", "train_4k", "--single-pod",
-              "--out", "/tmp/dryrun_test", "--force"], timeout=1800)
+              "--out", str(tmp_path), "--force"], timeout=1800)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert "OK" in r.stdout

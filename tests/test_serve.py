@@ -154,8 +154,8 @@ def test_flash_decode_padded_and_empty_slots():
 def test_flash_decode_paged_parity():
     rng = np.random.default_rng(4)
     b, kh, h, dk, ps, pps, npg = 3, 2, 4, 8, 8, 4, 16
-    pool_k = jnp.asarray(rng.standard_normal((npg, ps, kh, dk)), jnp.float32)
-    pool_v = jnp.asarray(rng.standard_normal((npg, ps, kh, dk)), jnp.float32)
+    pool_k = jnp.asarray(rng.standard_normal((npg, kh, ps, dk)), jnp.float32)
+    pool_v = jnp.asarray(rng.standard_normal((npg, kh, ps, dk)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((b, 1, h, dk)), jnp.float32)
     qpos = jnp.asarray([5, 20, 30], jnp.int32)
     # bind a logical prefix of pages per slot (unique physical pages > 0),
@@ -169,8 +169,8 @@ def test_flash_decode_paged_parity():
     # oracle over the gathered logical view
     gk = np.asarray(pool_k)[np.maximum(np.asarray(table), 0)]
     gv = np.asarray(pool_v)[np.maximum(np.asarray(table), 0)]
-    gk = gk.reshape(b, pps * ps, kh, dk)
-    gv = gv.reshape(b, pps * ps, kh, dk)
+    gk = gk.transpose(0, 1, 3, 2, 4).reshape(b, pps * ps, kh, dk)
+    gv = gv.transpose(0, 1, 3, 2, 4).reshape(b, pps * ps, kh, dk)
     lpos = np.tile(np.arange(pps * ps, dtype=np.int32), (b, 1))
     lpos = np.where(np.asarray(table)[:, lpos[0] // ps] >= 0, lpos, -1)
     ref = _oracle(q, gk, gv, qpos, lpos)

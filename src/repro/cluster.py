@@ -634,13 +634,16 @@ class KsaCluster:
         terminal attempt's grant/run span durations across its tasks,
         ``retry_s`` sums wall time spent inside earlier attempts, ``retries``
         counts non-terminal attempts, ``wall_s`` is the stage's span extent
-        (first span start → last span end). ``dominant_stage`` names the
-        stage with the largest wall_s."""
+        (first span start → last span end). ``phases`` maps each phase
+        name (:class:`repro.obs.phase` spans, children of ``run``) to its
+        seconds, summed over the terminal attempt as ``run_s`` is.
+        ``dominant_stage`` names the stage with the largest wall_s."""
         st = self.pipeline.status(campaign_id)
         stages: dict[str, dict] = {}
         for stage_name, task_ids in self.pipeline.stage_tasks(campaign_id):
             agg = {"tasks": len(task_ids), "traced": 0, "queue_s": 0.0,
-                   "run_s": 0.0, "retry_s": 0.0, "retries": 0, "wall_s": 0.0}
+                   "run_s": 0.0, "retry_s": 0.0, "retries": 0, "wall_s": 0.0,
+                   "phases": {}}
             lo, hi = None, None
             for tid in task_ids:
                 spans = self.broker.spans.trace(tid)
@@ -660,6 +663,10 @@ class KsaCluster:
                         agg["queue_s"] += s["dur_s"]
                     elif s["name"] == "run" and s.get("attempt") == term:
                         agg["run_s"] += s["dur_s"]
+                    elif s.get("parent") == "run" and \
+                            s.get("attempt") == term:
+                        ph = agg["phases"]
+                        ph[s["name"]] = ph.get(s["name"], 0.0) + s["dur_s"]
                 if term is not None:
                     earlier = [s for s in spans
                                if s["name"] in ("grant", "claim", "run", "revoke")

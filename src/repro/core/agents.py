@@ -57,6 +57,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
 
+from repro.obs import task_scope
+
 from .broker import Broker, Consumer, Producer
 from .computing import ClusterComputing, resolve_script
 from .lease import RevokeReason
@@ -681,7 +683,9 @@ class WorkerAgent(AgentBase):
             run.computing = comp
             ok = False
             try:
-                ok = comp.execute()
+                with task_scope(self.broker.spans, task.task_id,
+                                task.attempt):
+                    ok = comp.execute()
             finally:
                 if not self._crashed.is_set():
                     self._finish(task, ok)
@@ -759,7 +763,9 @@ class ClusterAgent(AgentBase):
             run.computing = comp
             ok = False
             try:
-                ok = comp.execute()
+                with task_scope(self.broker.spans, task.task_id,
+                                task.attempt):
+                    ok = comp.execute()
             finally:
                 if not self._crashed.is_set():
                     self._finish(task, ok)

@@ -12,6 +12,10 @@ In-process substrate (ISSUE 6):
   revoke → journal) records a span, linked across attempts. Surfaced via
   ``GET /trace/<task_id>`` and :meth:`repro.cluster.KsaCluster.trace` /
   ``campaign_report``.
+- :class:`phase` — a named host phase inside a task, a batch or a step:
+  a child span of the task's ``run`` in the span store (bound per thread
+  by :func:`task_scope`) and a ``jax.profiler.TraceAnnotation`` on the
+  device trace's own clock.
 - :func:`sample_rss_mb` — kernel-accounted process RSS for the agents'
   memory watchdog (self-reporting via ``report_mem`` stays as an
   override).
@@ -48,7 +52,7 @@ from .rss import sample_rss_mb
 from .series import TimeSeriesStore
 from .slo import AlertEngine, AlertRule, SloSpec
 from .telemetry import TelemetryCollector, TelemetryPublisher
-from .trace import NullSpanStore, SpanStore
+from .trace import NullSpanStore, SpanStore, phase, task_scope
 
 __all__ = [
     "Counter",
@@ -61,6 +65,8 @@ __all__ = [
     "topic_class",
     "SpanStore",
     "NullSpanStore",
+    "phase",
+    "task_scope",
     "sample_rss_mb",
     "TimeSeriesStore",
     "TelemetryPublisher",

@@ -17,13 +17,25 @@ The store is deliberately *lossy at the edges* — a fixed number of tasks
 (LRU-evicted) and a fixed number of spans per task — so tracing a week-long
 campaign cannot exhaust broker memory. Eviction counters are exposed via
 :meth:`stats` so silently dropped history is visible.
+
+Inside a task, :class:`phase` splits the ``run`` span: each phase is a
+child span (``parent="run"``) of the running attempt, and the same name is
+a ``jax.profiler.TraceAnnotation`` that the profiler stamps on its own
+clock, so a device trace shows what the host was doing in each gap.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
+import time
 from collections import OrderedDict, deque
+from contextvars import ContextVar
 
-__all__ = ["SpanStore", "NullSpanStore"]
+__all__ = ["SpanStore", "NullSpanStore", "phase", "task_scope"]
+
+# (span store, task id, attempt) of the task the current thread runs
+_TASK: ContextVar = ContextVar("repro_obs_task", default=None)
 
 
 class SpanStore:
@@ -170,3 +182,61 @@ class NullSpanStore:
     def stats(self) -> dict:
         return {"tasks": 0, "spans": 0, "evicted_tasks": 0,
                 "dropped_spans": 0}
+
+
+@contextlib.contextmanager
+def task_scope(store, task_id: str, attempt: int):
+    """Bind the task the calling thread runs, so that :class:`phase` files
+    its spans under that task's attempt in ``store``. The agents wrap each
+    task's ``execute()`` in it."""
+    token = _TASK.set((store, task_id, attempt))
+    try:
+        yield
+    finally:
+        _TASK.reset(token)
+
+
+@functools.cache
+def _trace_annotation():
+    # jax is imported on first use, so the broker imports without it
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+class phase:
+    """Time one phase of a task, a batch or a step::
+
+        with phase("knot.synthesize"):
+            coords, _ = synthesize_batch(kept_ids, n_points)
+
+    Inside a task bound by :func:`task_scope` it adds, on exit, a child
+    span of the task's ``run`` to the broker's span store.
+    ``task_span=False`` keeps a per-step phase of a long-lived loop out of
+    the bounded per-task store; it still reaches the profiler. ``dur_s``
+    holds the duration after exit. ``KsaCluster(obs=False)`` nulls the
+    span store, so the phase then records nothing but the annotation.
+    """
+
+    __slots__ = ("name", "task_span", "dur_s", "_ann", "_wall", "_t0")
+
+    def __init__(self, name: str, *, task_span: bool = True):
+        self.name = name
+        self.task_span = task_span
+        self.dur_s = 0.0
+
+    def __enter__(self) -> "phase":
+        self._ann = _trace_annotation()(self.name)
+        self._ann.__enter__()
+        self._wall = time.time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur_s = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        task = _TASK.get() if self.task_span else None
+        if task is not None:
+            store, task_id, attempt = task
+            store.add(task_id, self.name, self._wall,
+                      self._wall + self.dur_s, attempt=attempt,
+                      parent="run")

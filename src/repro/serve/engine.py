@@ -47,6 +47,7 @@ from repro.core import ClusterComputing, register_script
 from repro.models.config import ModelConfig
 from repro.models.transformer import (init_caches, init_paged_caches,
                                       paged_layout)
+from repro.obs import phase
 from repro.train.step import make_serve_step
 
 from .paged import PageAllocator
@@ -311,8 +312,11 @@ class ServeEngine:
         consume their next prompt token; generating slots append). Returns
         finished (request_id, tokens) pairs.
 
-        Three phases: assemble (lock), device call (no lock — admissions
-        proceed concurrently), apply (lock, generation-checked)."""
+        Four phases, each a ``serve.<phase>`` span: assemble (lock),
+        dispatch and sync (no lock — admissions proceed concurrently),
+        apply (generation-checked; the span opens once the lock is held). An idle engine takes no step and
+        records no phase. ``ksa_serve_step_seconds`` is dispatch plus
+        sync."""
         if not self._step_guard.acquire(blocking=False):
             raise RuntimeError("ServeEngine.step is single-driver; a step "
                                "is already in flight")
@@ -326,57 +330,60 @@ class ServeEngine:
             active = self._active()
             if not active:
                 return []
-            self._apply_resets()
-            col = np.zeros((self.n_slots, 1), np.int32)
-            pos = np.zeros((self.n_slots,), np.int32)
-            stepped: list[int] = []
-            stalled: list[int] = []
-            gens: dict[int, int] = {}
-            for i in active:
-                s = self.slots[i]
-                if self.allocator is not None and \
-                        not self.allocator.ensure(i, s.position):
-                    stalled.append(i)
-                    continue  # pool exhausted: slot stalls, retries next step
-                if s.position < len(s.prompt):
-                    col[i, 0] = s.prompt[s.position]
-                else:
-                    col[i, 0] = s.tokens[-1] if s.tokens else s.prompt[-1]
-                pos[i] = s.position
-                stepped.append(i)
-                gens[i] = s.gen
-            if not stepped:
-                return []
-            caches = self.caches
-            pages = None
-            if self.allocator is not None:
-                table = self.allocator.table
-                if stalled:
-                    # a stalled slot still rides through the device call as a
-                    # garbage lane (col=0, pos=0); clearing its row makes the
-                    # K/V scatter clamp to the trash page instead of hitting
-                    # its real, still-bound position-0 page.
-                    table = table.copy()
-                    table[stalled] = -1
-                pages = jnp.asarray(table)
+            with phase("serve.assemble", task_span=False):
+                self._apply_resets()
+                col = np.zeros((self.n_slots, 1), np.int32)
+                pos = np.zeros((self.n_slots,), np.int32)
+                stepped: list[int] = []
+                stalled: list[int] = []
+                gens: dict[int, int] = {}
+                for i in active:
+                    s = self.slots[i]
+                    if self.allocator is not None and \
+                            not self.allocator.ensure(i, s.position):
+                        stalled.append(i)
+                        continue  # pool exhausted: stall, retry next step
+                    if s.position < len(s.prompt):
+                        col[i, 0] = s.prompt[s.position]
+                    else:
+                        col[i, 0] = s.tokens[-1] if s.tokens else \
+                            s.prompt[-1]
+                    pos[i] = s.position
+                    stepped.append(i)
+                    gens[i] = s.gen
+                if not stepped:
+                    return []
+                caches = self.caches
+                pages = None
+                if self.allocator is not None:
+                    table = self.allocator.table
+                    if stalled:
+                        # a stalled slot still rides through the device call
+                        # as a garbage lane (col=0, pos=0); clearing its row
+                        # makes the K/V scatter clamp to the trash page
+                        # instead of hitting its real, still-bound
+                        # position-0 page.
+                        table = table.copy()
+                        table[stalled] = -1
+                    pages = jnp.asarray(table)
 
-        t0 = time.time()
-        if pages is not None:
-            logits, next_ids, new_caches = self._serve(
-                self.params, jnp.asarray(col), caches, jnp.asarray(pos),
-                pages)
-        else:
-            logits, next_ids, new_caches = self._serve(
-                self.params, jnp.asarray(col), caches, jnp.asarray(pos))
-        self.last_logits = logits
-        next_ids = np.asarray(next_ids)  # device sync, still outside the lock
-        if self.step_latency_s:
-            # benchmark knob: emulate an accelerator-bound step on hosts
-            # where the smoke model underruns real device latency.
-            time.sleep(self.step_latency_s)
-        dt = time.time() - t0
+        with phase("serve.dispatch", task_span=False) as dispatch:
+            if pages is not None:
+                logits, next_ids, new_caches = self._serve(
+                    self.params, jnp.asarray(col), caches, jnp.asarray(pos),
+                    pages)
+            else:
+                logits, next_ids, new_caches = self._serve(
+                    self.params, jnp.asarray(col), caches, jnp.asarray(pos))
+            self.last_logits = logits
+        with phase("serve.sync", task_span=False) as sync:
+            next_ids = np.asarray(next_ids)  # device sync, outside the lock
+            if self.step_latency_s:
+                # benchmark knob: emulate an accelerator-bound step on hosts
+                # where the smoke model underruns real device latency.
+                time.sleep(self.step_latency_s)
 
-        with self._lock:
+        with self._lock, phase("serve.apply", task_span=False):
             if stalled:
                 # the garbage lane also advanced per-slot state (recurrent
                 # ssd/rglru h/conv, ring K/V at index 0) — roll those lanes
@@ -413,7 +420,7 @@ class ServeEngine:
                     finished.append((s.request_id, list(s.tokens)))
             self._recent.append((now, n_tokens))
             if self._m is not None:
-                self._m["step"].observe(dt)
+                self._m["step"].observe(dispatch.dur_s + sync.dur_s)
                 if n_tokens:
                     self._m["tokens"].inc(n_tokens)
                 self._m["slots_active"].set(len(self._active()))

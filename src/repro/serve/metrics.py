@@ -36,7 +36,8 @@ def register_serve_metrics(registry: "MetricsRegistry") -> dict:
             labels=("replica",), buckets=_LATENCY_BUCKETS),
         "step": registry.histogram(
             "ksa_serve_step_seconds",
-            "One continuous-batching decode step (device call).",
+            "Dispatch and wait for one decode step (continuous "
+            "batching).",
             labels=("replica",), buckets=_LATENCY_BUCKETS),
         "tokens": registry.counter(
             "ksa_serve_tokens_total",

@@ -39,6 +39,7 @@ import jax
 
 from repro.core import ClusterComputing, Resources, register_script
 from repro.core.scheduling import ResourceProfile
+from repro.obs import phase
 
 from .engine import ServeEngine
 
@@ -184,26 +185,36 @@ class ServeReplicaSet:
 
     def _drive_once(self, r: int) -> bool:
         """One driver iteration: admit from the queue, step, resolve.
-        Returns True if there was any work."""
+        Returns True if there was any work. An iteration with work is a
+        ``serve.loop`` span and its admission a ``serve.admit`` span, so
+        ``serve.loop`` is the loop's period while it serves; an idle poll
+        records neither. The spans go to the profiler only: a replica task
+        lives as long as the deployment, and its steps would overrun the
+        bounded per-task span store."""
         eng = self.engines[r]
         q = self._queues[r]
-        while True:
-            with self._lock:
-                if not q:
-                    break
-                head = q[0]
-            if not eng.add_request(head.request_id, head.prompt,
-                                   head.max_new,
-                                   arrival_ts=head.arrival_ts):
-                break
-            with self._lock:
-                if q and q[0] is head:
-                    q.popleft()
-        finished = eng.step()
-        for rid, toks in finished:
-            self._resolve(rid, toks)
         with self._lock:
-            busy = bool(q) or bool(eng._active())
+            if not q and not eng._active():
+                return False
+        with phase("serve.loop", task_span=False):
+            with phase("serve.admit", task_span=False):
+                while True:
+                    with self._lock:
+                        if not q:
+                            break
+                        head = q[0]
+                    if not eng.add_request(head.request_id, head.prompt,
+                                           head.max_new,
+                                           arrival_ts=head.arrival_ts):
+                        break
+                    with self._lock:
+                        if q and q[0] is head:
+                            q.popleft()
+            finished = eng.step()
+            for rid, toks in finished:
+                self._resolve(rid, toks)
+            with self._lock:
+                busy = bool(q) or bool(eng._active())
         return busy or bool(finished)
 
     def _resolve(self, rid: str, tokens: list[int]) -> None:

@@ -91,3 +91,25 @@ def test_knot_campaign_end_to_end():
         a2.stop()
         mon.stop()
         broker.close()
+
+
+def test_pipeline_screen_task_records_its_phases():
+    """Each screen task's span chain holds its four phases, children of
+    its run, and the campaign report sums them per stage."""
+    from repro.cluster import KsaCluster
+    with KsaCluster(prefix="kph", workers=1, worker_slots=1,
+                    poll_interval_s=0.005) as c:
+        cid = c.submit_campaign(knots.knots_pipeline(8, n_points=48),
+                                list(range(8)))
+        assert c.wait_campaign(cid, timeout=240.0).state == "COMPLETED"
+        tid = dict(c.pipeline.stage_tasks(cid))["screen"][0]
+        spans = c.trace(tid)
+        rep = c.campaign_report(cid)
+    kids = [s["name"] for s in spans if s.get("parent") == "run"]
+    assert kids == ["knot.quality", "knot.synthesize", "knot.writhe",
+                    "knot.select"]
+    screen = rep["stages"]["screen"]
+    assert list(screen["phases"]) == kids
+    assert sum(screen["phases"].values()) <= screen["run_s"]
+    assert list(rep["stages"]["localize"]["phases"]) == [
+        "knot.synthesize", "knot.writhe", "knot.core"]

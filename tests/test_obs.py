@@ -15,14 +15,14 @@ import pytest
 
 from repro.cluster import KsaCluster
 from repro.core import (Broker, ClusterComputing, Consumer, FairShare,
-                        RevokeReason, Submitter)
+                        RevokeReason, Submitter, register_script)
 from repro.core.messages import topic_names
 from repro.core.monitor import ROUTES
 from repro.obs import (DEFAULT_BUCKETS, AlertEngine, AlertRule,
                        FlightRecorder, MetricsRegistry, NullSpanStore,
                        SloSpec, SpanStore, TelemetryCollector,
-                       TimeSeriesStore, merge_renders, sample_rss_mb,
-                       topic_class)
+                       TimeSeriesStore, merge_renders, phase,
+                       sample_rss_mb, task_scope, topic_class)
 from repro.pipeline import PipelineAgent, PipelineSpec, RetryPolicy, Stage
 
 
@@ -484,6 +484,84 @@ def test_campaign_report_splits_queue_run_retry():
         assert rep["dominant_stage"] in ("a", "b")
         assert rep["wall_s"] >= max(s["run_s"] for s in
                                     rep["stages"].values()) / 2
+        assert a["phases"] == {}  # the sleep task records no phase
+
+
+@register_script("obs_phased")
+class _PhasedComputing(ClusterComputing):
+    """Two phases inside a task; the first attempt fails after its first
+    phase when ``fail_first`` is set."""
+
+    def run(self):
+        slow = bool(self.params.get("fail_first")) and self.attempt == 0
+        with phase("t.work"):
+            time.sleep(0.15 if slow else 0.01)
+        if slow:
+            raise RuntimeError("first attempt fails")
+        with phase("t.tail"):
+            pass
+        return {"attempt": self.attempt}
+
+
+def test_phase_outside_a_task_stores_no_span():
+    store = SpanStore()
+    with phase("p.outside") as p:
+        time.sleep(0.01)
+    assert store.tasks() == [] and p.dur_s >= 0.009
+    with task_scope(store, "t-1", 2):
+        with phase("p.inside"):
+            pass
+        with phase("p.loop", task_span=False):
+            pass
+    with phase("p.after"):
+        pass
+    spans = store.trace("t-1")
+    assert [(s["name"], s["task_id"], s["attempt"], s["parent"])
+            for s in spans] == [("p.inside", "t-1", 2, "run")]
+
+
+def test_phase_inside_a_task_is_a_child_of_its_run():
+    with KsaCluster(prefix="obs15", workers=1, worker_slots=1,
+                    poll_interval_s=0.005) as c:
+        tid = c.submit("obs_phased")
+        assert c.wait_all([tid], timeout=10.0)
+        spans = c.trace(tid)
+    run = [s for s in spans if s["name"] == "run"]
+    kids = [s for s in spans if s.get("parent") == "run"]
+    assert [s["name"] for s in kids] == ["t.work", "t.tail"]
+    assert all(s["task_id"] == tid and s["attempt"] == run[0]["attempt"]
+               for s in kids)
+    assert run[0]["start"] <= kids[0]["start"] <= kids[-1]["end"] \
+        <= run[0]["end"]
+
+
+def test_phase_under_obs_off_records_nothing():
+    store = NullSpanStore()
+    with KsaCluster(prefix="obs16", workers=1, worker_slots=1, obs=False,
+                    poll_interval_s=0.005) as c:
+        tid = c.submit("obs_phased")
+        assert c.wait_all([tid], timeout=10.0)
+        assert c.trace(tid) == [] and c.broker.spans.tasks() == []
+    with task_scope(store, "t-2", 0), phase("p.off"):
+        pass
+    assert store.trace("t-2") == [] and store.tasks() == []
+
+
+def test_campaign_report_phases_sum_the_terminal_attempt():
+    spec = PipelineSpec("obs-ph", [
+        Stage("a", "obs_phased", fan_out=1, params={"fail_first": True},
+              retry=RetryPolicy(max_attempts=2)),
+    ])
+    with KsaCluster(prefix="obs17", workers=1, worker_slots=2,
+                    poll_interval_s=0.005) as c:
+        cid = c.submit_campaign(spec, list(range(2)))
+        assert c.wait_campaign(cid, timeout=30.0).state == "COMPLETED"
+        a = c.campaign_report(cid)["stages"]["a"]
+    assert a["retries"] == 2 and a["retry_s"] >= 2 * 0.15
+    # both tasks' second attempts: 10 ms each, not the failed 150 ms
+    assert set(a["phases"]) == {"t.work", "t.tail"}
+    assert 2 * 0.009 <= a["phases"]["t.work"] < 0.15
+    assert a["phases"]["t.work"] + a["phases"]["t.tail"] <= a["run_s"]
 
 
 # ---------------------------------------------------------------------------

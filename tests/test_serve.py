@@ -17,9 +17,12 @@ from repro.kernels.flash_decode import (flash_decode, flash_decode_paged,
                                         flash_decode_xla)
 from repro.models import init_params, model_spec
 from repro.models.transformer import forward
+from repro.obs import phase
 from repro.obs.metrics import MetricsRegistry
 from repro.serve import (PageAllocator, ServeEngine, ServeReplicaSet,
                          register_serve_metrics, ttft_slo)
+from repro.serve import engine as serve_engine
+from repro.serve import replica as serve_replica
 
 
 @pytest.fixture(scope="module")
@@ -524,3 +527,50 @@ def test_serve_metrics_registered_and_exported(small_model):
         assert f"# TYPE {name}" in text, name
     assert 'ksa_serve_tokens_total{replica="r9"} 3' in text
     assert 'event="admitted"' in text and 'event="completed"' in text
+
+
+def _record_phases(monkeypatch, module) -> list:
+    """Swap ``module``'s ``phase`` for one that lists (name, seconds)."""
+    seen = []
+
+    class _Recorded(phase):
+        __slots__ = ()
+
+        def __exit__(self, *exc):
+            super().__exit__(*exc)
+            seen.append((self.name, self.dur_s))
+
+    monkeypatch.setattr(module, "phase", _Recorded)
+    return seen
+
+
+def test_one_step_records_each_engine_phase_once(small_model, monkeypatch):
+    cfg, params = small_model
+    reg = MetricsRegistry()
+    eng = ServeEngine(cfg, params, n_slots=2, max_len=64, paged=True,
+                      page_size=16, registry=reg, replica="r8")
+    seen = _record_phases(monkeypatch, serve_engine)
+    step = register_serve_metrics(reg)["step"].labels(replica="r8")
+    assert eng.step() == []  # idle: no step, no phase
+    assert step.count == 0 and seen == []
+    assert eng.add_request("p0", [1, 2, 3], 2)
+    eng.step()
+    assert [n for n, _ in seen] == ["serve.assemble", "serve.dispatch",
+                                    "serve.sync", "serve.apply"]
+    # the step histogram keeps its meaning: dispatch plus sync
+    dur = dict(seen)
+    assert step.count == 1
+    assert step.sum == pytest.approx(dur["serve.dispatch"]
+                                     + dur["serve.sync"])
+
+
+def test_an_idle_replica_poll_records_no_loop_phase(small_model,
+                                                    monkeypatch):
+    cfg, params = small_model
+    rs = ServeReplicaSet(cfg, params, n_replicas=1,
+                         engine_kw=dict(n_slots=2, max_len=64))
+    seen = _record_phases(monkeypatch, serve_replica)
+    assert rs._drive_once(0) is False and seen == []
+    rs.submit("q0", [1, 2, 3], 2)
+    assert rs._drive_once(0) is True
+    assert [n for n, _ in seen] == ["serve.admit", "serve.loop"]

@@ -30,11 +30,12 @@ Three callables share the contract:
 * :func:`decode_attention` — backend dispatch between the two.
 
 :func:`flash_decode_paged` / :func:`decode_attention_paged` are the paged
-variants: KV lives in a physical page pool ``(P, K, page_size, D)`` and a
-per-slot page table ``(B, pages_per_slot)`` (-1 = unbound) is scalar-
-prefetched so the k/v ``index_map`` gathers pages directly — no logical
-cache is ever materialized, and work scales with *bound pages*, not
-``max_len``.
+variants: KV lives in a physical page pool ``(P, K, page_size, D)``, or in
+every layer's pools stacked ``(L, P, K, page_size, D)`` with the layer
+index beside it, and a per-slot page table ``(B, pages_per_slot)`` (-1 =
+unbound) is scalar-prefetched so the k/v ``index_map`` gathers pages
+directly from the stack — no logical cache and no per-layer slice is ever
+materialized.
 
 Every block's last two dims are whole array dims or (8, 128)-aligned, and
 the online-softmax stats are 2-D ``(G, 1)`` VMEM scratch, so both kernels
@@ -284,9 +285,10 @@ def decode_attention(q, k, v, q_positions, k_positions=None, *,
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(qpos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float,
+def _paged_kernel(qpos_ref, table_ref, layer_ref, q_ref, k_ref, v_ref,
+                  o_ref, m_scr, l_scr, acc_scr, *, scale: float,
                   window: int | None, page_size: int, n_pages: int):
+    del layer_ref  # read by the k/v index map only
     bi = pl.program_id(0)
     pi = pl.program_id(2)
 
@@ -316,22 +318,35 @@ def _paged_kernel(qpos_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         _finalize(o_ref, l_scr, acc_scr)
 
 
+def _stacked(pool_k, pool_v, layer):
+    """A single layer's ``(P, K, page_size, D)`` pools as a stack of one
+    (a free reshape) at layer 0; a stack ``(L, P, ...)`` as it is."""
+    if pool_k.ndim == 4:
+        return pool_k[None], pool_v[None], jnp.zeros((), jnp.int32)
+    assert layer is not None, "a stacked pool needs its layer index"
+    return pool_k, pool_v, jnp.asarray(layer, jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
 def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
-                       q_positions: jax.Array, page_table: jax.Array, *,
+                       q_positions: jax.Array, page_table: jax.Array,
+                       layer: jax.Array | int | None = None, *,
                        window: int | None = None, scale: float | None = None,
                        interpret: bool = False) -> jax.Array:
-    """q: (B, 1, H, Dk); pool_k: (P, K, page_size, Dk); pool_v likewise with
-    Dv; page_table: (B, pages_per_slot) int32, -1 = unbound (page 0 is the
+    """q: (B, 1, H, Dk); pool_k: (P, K, page_size, Dk), or the stack
+    (L, P, K, page_size, Dk) read at ``layer``; pool_v likewise with Dv;
+    page_table: (B, pages_per_slot) int32, -1 = unbound (page 0 is the
     allocator's reserved trash page). -> (B, 1, H, Dv).
 
-    The page table is scalar-prefetched so the k/v ``index_map`` gathers the
-    physical page per grid step — unbound entries clamp to page 0 and are
+    The page table and the layer index are scalar-prefetched so the k/v
+    ``index_map`` gathers the physical page of that layer per grid step —
+    the stack is read in place; unbound entries clamp to page 0 and are
     masked out by ``page >= 0`` inside the kernel.
     """
     b, sq, h, dk = q.shape
     assert sq == 1
-    _, kh, page_size, _ = pool_k.shape
+    pool_k, pool_v, layer = _stacked(pool_k, pool_v, layer)
+    _, _, kh, page_size, _ = pool_k.shape
     dv = pool_v.shape[-1]
     g = h // kh
     n_pages = page_table.shape[1]
@@ -342,20 +357,20 @@ def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
                                page_size=page_size, n_pages=n_pages)
 
-    def kv_map(bi, hi, pi, qp, table):
-        return (jnp.maximum(table[bi, pi], 0), hi, 0, 0)
+    def kv_map(bi, hi, pi, qp, table, lyr):
+        return (lyr[0], jnp.maximum(table[bi, pi], 0), hi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, kh, n_pages),
         in_specs=[
             pl.BlockSpec((1, 1, g, dk),
-                         lambda bi, hi, pi, qp, tb: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, dk), kv_map),
-            pl.BlockSpec((1, 1, page_size, dv), kv_map),
+                         lambda bi, hi, pi, qp, tb, ly: (bi, hi, 0, 0)),
+            pl.BlockSpec((None, 1, 1, page_size, dk), kv_map),
+            pl.BlockSpec((None, 1, 1, page_size, dv), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda bi, hi, pi, qp, tb: (bi, hi, 0, 0)),
+                               lambda bi, hi, pi, qp, tb, ly: (bi, hi, 0, 0)),
         scratch_shapes=_stat_scratch(g, dv),
     )
     out = pl.pallas_call(
@@ -363,23 +378,25 @@ def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dv), q.dtype),
         interpret=interpret,
     )(q_positions.astype(jnp.int32), page_table.astype(jnp.int32),
-      qt, pool_k, pool_v)
+      layer.reshape(1), qt, pool_k, pool_v)
     return out.reshape(b, 1, h, dv)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "bounded"))
 def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
                            pool_v: jax.Array, q_positions: jax.Array,
-                           page_table: jax.Array, *,
+                           page_table: jax.Array,
+                           layer: jax.Array | int | None = None, *,
                            window: int | None = None,
                            scale: float | None = None,
                            bounded: bool = True) -> jax.Array:
     """Paged decode through XLA: a dynamic-trip-count loop over page blocks,
-    gathering one physical page per slot per step. Work scales with bound
-    pages (occupancy), never materializing the logical cache."""
+    gathering one physical page of ``layer`` per slot per step. Work scales
+    with bound pages (occupancy), never materializing the logical cache."""
     b, sq, h, dk = q.shape
     assert sq == 1
-    _, kh, page_size, _ = pool_k.shape
+    pool_k, pool_v, layer = _stacked(pool_k, pool_v, layer)
+    _, _, kh, page_size, _ = pool_k.shape
     dv = pool_v.shape[-1]
     g = h // kh
     n_pages = page_table.shape[1]
@@ -396,8 +413,8 @@ def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
     def body(i, carry):
         m_run, l_run, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(table, i, 1, axis=1)[:, 0]
-        kc = pool_k[jnp.maximum(pages, 0)]     # (B, K, page_size, Dk)
-        vc = pool_v[jnp.maximum(pages, 0)]
+        kc = pool_k[layer, jnp.maximum(pages, 0)]   # (B, K, page_size, Dk)
+        vc = pool_v[layer, jnp.maximum(pages, 0)]
         kp = i * page_size + jnp.arange(page_size, dtype=jnp.int32)[None, :]
         mask = (pages >= 0)[:, None] & (kp <= qp[:, None])
         if window is not None:
@@ -424,12 +441,23 @@ def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
     return out.reshape(b, 1, h, dv).astype(q.dtype)
 
 
-def decode_attention_paged(q, pool_k, pool_v, q_positions, page_table, *,
-                           window=None, interpret=False):
+def decode_attention_paged(q, pool_k, pool_v, q_positions, page_table,
+                           layer=None, *, window=None, interpret=False):
     """Backend dispatch for the paged cache path, by the same rule as
-    :func:`decode_attention`."""
+    :func:`decode_attention`. ``q`` is at the head dim D, which k and v
+    share; the pools' rows may be zero-padded past it (a paged cache's
+    are, see ``models.attention.paged_row``). q is padded to the rows, so
+    its zero lanes add nothing to a score, the scale stays 1/sqrt(D), and
+    the output's zero lanes, from v's, are cut off."""
+    d = q.shape[-1]
+    width = pool_k.shape[-1]
+    if width != d:
+        q = jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, width - d)])
+    kw = dict(window=window, scale=1.0 / math.sqrt(d))
     if on_tpu() or interpret:
-        return flash_decode_paged(q, pool_k, pool_v, q_positions, page_table,
-                                  window=window, interpret=not on_tpu())
-    return flash_decode_paged_xla(q, pool_k, pool_v, q_positions, page_table,
-                                  window=window)
+        out = flash_decode_paged(q, pool_k, pool_v, q_positions, page_table,
+                                 layer, interpret=not on_tpu(), **kw)
+    else:
+        out = flash_decode_paged_xla(q, pool_k, pool_v, q_positions,
+                                     page_table, layer, **kw)
+    return out[..., :d]

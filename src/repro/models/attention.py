@@ -212,9 +212,11 @@ def attention_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
 
     ``cache``: {"k": (B, S_cache, K, Dh), "v": ...}. If ``S_cache == window``
     for a local layer, the cache is treated as a **ring buffer**. A paged
-    cache instead holds {"pool_k": (P, K, page_size, Dh), "pool_v": ...}
-    and requires ``pages``: the (B, pages_per_slot) int32 page table
-    (-1 = unbound; page 0 is the allocator's trash page).
+    cache instead holds {"pool_k": (P, K, page_size, Dh), "pool_v": ...},
+    or the stacked pools of every layer with this one's ``"layer"`` index
+    (see :func:`_paged_decode`), and requires ``pages``: the
+    (B, pages_per_slot) int32 page table (-1 = unbound; page 0 is the
+    allocator's trash page).
     ``cache_index``: scalar int32 — count of tokens already cached.
     """
     b, s, d = x.shape
@@ -326,38 +328,58 @@ def _paged_decode(cfg: ModelConfig, q: jax.Array, k: jax.Array,
                   ) -> tuple[dict, jax.Array]:
     """One decode step against a paged KV cache.
 
-    The new token is scattered into its slot's current page (slots whose
-    table row is unbound clamp to the reserved trash page 0), then attention
-    reads through the page table. ``decode_kernel="flash"`` uses the fused
-    paged kernel; "chunked" gathers the logical view and runs the reference
-    — pages are bound in logical order, so offsets past a slot's position
-    hold garbage but are causally masked (``k_pos > q_pos``).
+    The pools are one layer's ``(P, K, page_size, D)``, or every layer's
+    stacked ``(L, P, K, page_size, D)`` with this layer's index under
+    ``cache["layer"]`` (the scan carries the stack whole, so no layer is
+    sliced out or written back). ``D`` is :func:`paged_row` of the head
+    dim; k and v are zero-padded to it. Each slot's new token is written in
+    place into its current page, one ``dynamic_update_slice`` per slot
+    (slots whose table row is unbound clamp to the reserved trash page 0):
+    a scatter would ask for a layout that the kernel cannot read, and XLA
+    would copy the pool to convert. The loop over slots unrolls at trace
+    time, 2·B updates in the one scan body; B is the engine's slot count,
+    fixed per deployment, so the step's HLO grows with it but never with
+    depth or traffic. Attention then reads through the page table. ``decode_kernel="flash"`` uses the fused paged kernel; "chunked"
+    gathers the logical view and runs the reference — pages are bound in
+    logical order, so offsets past a slot's position hold garbage but are
+    causally masked (``k_pos > q_pos``).
     """
     b = q.shape[0]
     dt = q.dtype
-    cdt = cache["pool_k"].dtype
-    page_size = cache["pool_k"].shape[2]
+    ck, cv = cache["pool_k"], cache["pool_v"]
+    layer = cache.get("layer")
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
+    cdt = ck.dtype
+    page_size = ck.shape[-2]
     rows = jnp.arange(b, dtype=jnp.int32)
     page = pages[rows, positions // page_size]
     page = jnp.maximum(page, 0)
     off = positions % page_size
-    ck = cache["pool_k"].at[page, :, off].set(k[:, 0].astype(cdt))
-    cv = cache["pool_v"].at[page, :, off].set(v[:, 0].astype(cdt))
+    dk, dv = k.shape[-1], v.shape[-1]
+    k = _pad_last(k, ck.shape[-1])          # rows fill whole lane tiles
+    v = _pad_last(v, cv.shape[-1])
+    zero = jnp.zeros((), jnp.int32)
+    row = (1,) * len(lead) + (1, ck.shape[-3], 1)   # one slot's (K, D) row
+    for i in range(b):
+        at = lead + (page[i], zero, off[i], zero)
+        ck = jax.lax.dynamic_update_slice(
+            ck, k[i, 0].astype(cdt).reshape(row + (ck.shape[-1],)), at)
+        cv = jax.lax.dynamic_update_slice(
+            cv, v[i, 0].astype(cdt).reshape(row + (cv.shape[-1],)), at)
     new_cache = {"pool_k": ck, "pool_v": cv}
     if cfg.decode_kernel == "flash":
         from repro.kernels.flash_decode import decode_attention_paged
         out = decode_attention_paged(q, ck.astype(dt), cv.astype(dt),
-                                     positions, pages, window=window,
+                                     positions, pages, layer, window=window,
                                      interpret=cfg.kernel_interpret)
         return new_cache, out
     n_pages = pages.shape[1]
     tbl = jnp.maximum(pages, 0)
-    kh, dk = ck.shape[1], ck.shape[3]
-    dv = cv.shape[3]
+    kh = ck.shape[-3]
     # (B, n_pages, K, page_size, D) -> logical (B, n_pages*page_size, K, D)
-    k_lin = ck[tbl].transpose(0, 1, 3, 2, 4).reshape(
+    k_lin = ck[lead + (tbl,)][..., :dk].transpose(0, 1, 3, 2, 4).reshape(
         b, n_pages * page_size, kh, dk)
-    v_lin = cv[tbl].transpose(0, 1, 3, 2, 4).reshape(
+    v_lin = cv[lead + (tbl,)][..., :dv].transpose(0, 1, 3, 2, 4).reshape(
         b, n_pages * page_size, kh, dv)
     kp = (jnp.arange(n_pages, dtype=jnp.int32)[:, None] * page_size +
           jnp.arange(page_size, dtype=jnp.int32)[None, :])
@@ -369,6 +391,24 @@ def _paged_decode(cfg: ModelConfig, q: jax.Array, k: jax.Array,
                             kv_chunk=cfg.kv_chunk, k_valid=kp >= 0,
                             score_dtype=jnp.dtype(cfg.score_dtype))
     return new_cache, out
+
+
+def paged_row(head_dim: int) -> int:
+    """Width of a paged pool's rows: the head dim rounded up to whole
+    128-lane tiles, zeros past it. The TPU's default layout of such a pool
+    is row-major, the layout the paged kernel reads, so the pool crosses
+    each step in place with no layout to pin (with 64-wide rows the
+    default puts the page axis minor, and every step would relay the pool
+    both ways). A head dim of 64 stores twice its bytes."""
+    return -(-head_dim // 128) * 128
+
+
+def _pad_last(x: jax.Array, width: int) -> jax.Array:
+    """``x`` with its last axis zero-padded to ``width``."""
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def init_kv_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,

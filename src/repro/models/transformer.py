@@ -20,7 +20,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from .attention import attention_block, attention_spec, init_kv_cache
+from .attention import attention_block, attention_spec, init_kv_cache, paged_row
 from .config import ModelConfig
 from .layers import embed, embedding_spec, mlp, mlp_spec, rmsnorm, rmsnorm_spec, unembed
 from .mla import init_mla_cache, mla_block, mla_spec
@@ -191,14 +191,27 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
     if cfg.n_periods > 0:
         params_p = params["periods"]
         caches_p = caches.get("periods") if decode else None
+        # paged pools ride the carry whole, stacked (L, P, K, page, Dh), and
+        # each layer writes and reads its own slice in place: as scan xs/ys
+        # every layer's pool would be sliced out, relaid and written back.
+        # Every other cache leaf is scanned as before.
+        pools = {i: c for i, c in (caches_p or {}).items() if "pool_k" in c}
+        layer_idx = None
+        if pools:
+            caches_p = {i: c for i, c in caches_p.items() if i not in pools}
+            layer_idx = jnp.arange(cfg.n_periods, dtype=jnp.int32)
 
         def body(carry, xs):
-            h, auxc = carry
-            p_i, c_i = xs
+            h, auxc, pools_c = carry
+            p_i, c_i, l_i = xs
+            if pools_c:
+                c_i = {**c_i, **{i: {**c, "layer": l_i}
+                                 for i, c in pools_c.items()}}
             h, nc, a = _apply_period(p_i, cfg, h, positions=positions,
                                      caches_p=c_i, cache_index=cache_index,
                                      dist=dist, decode=decode, pages=pages)
-            return (h, auxc + a), nc
+            pools_c = {i: nc.pop(i) for i in pools_c}
+            return (h, auxc + a, pools_c), nc
 
         if remat != "none":
             policy = {
@@ -208,11 +221,11 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
             }[remat]
             body = jax.checkpoint(body, policy=policy,
                                   prevent_cse=False)
-        (x, aux_total), nc_stack = jax.lax.scan(body, (x, aux_total),
-                                                (params_p, caches_p),
-                                                unroll=unroll)
+        (x, aux_total, pools), nc_stack = jax.lax.scan(
+            body, (x, aux_total, pools), (params_p, caches_p, layer_idx),
+            unroll=unroll)
         if decode:
-            new_caches["periods"] = nc_stack
+            new_caches["periods"] = {**nc_stack, **pools}
 
     if cfg.n_remainder:
         caches_t = caches.get("tail") if decode else None
@@ -257,6 +270,14 @@ def _cache_for(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     return None
 
 
+@partial(jax.jit, static_argnums=0)
+def _stack(n: int, a: jax.Array) -> jax.Array:
+    """``a`` stacked ``n`` deep for a scan, in one device call that
+    allocates the stack alone (an eager broadcast and its copy allocate
+    it twice, which for a paged pool is gigabytes at set-up)."""
+    return jnp.broadcast_to(a[None], (n,) + a.shape)
+
+
 def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
     """Decode cache pytree matching the scan layout of :func:`forward`."""
     out: dict = {}
@@ -266,8 +287,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
             c = _cache_for(cfg, kind, batch, max_len, dtype)
             if c is not None:
                 per[str(i)] = jax.tree.map(
-                    lambda a: jnp.broadcast_to(
-                        a[None], (cfg.n_periods,) + a.shape).copy(), c)
+                    partial(_stack, cfg.n_periods), c)
         out["periods"] = per
     if cfg.n_remainder:
         tail = {}
@@ -298,11 +318,12 @@ def _paged_cache_for(cfg: ModelConfig, kind: str, batch: int, max_len: int,
         if kind == "local" and min(max_len, cfg.window_size) < max_len:
             # ring buffers are already O(window); keep them dense.
             return init_kv_cache(cfg, kind, batch, max_len, dtype)
+        row = paged_row(cfg.head_dim)
         return {
-            "pool_k": jnp.zeros((n_pages, cfg.n_kv_heads, page_size,
-                                 cfg.head_dim), dtype),
-            "pool_v": jnp.zeros((n_pages, cfg.n_kv_heads, page_size,
-                                 cfg.head_dim), dtype),
+            "pool_k": jnp.zeros((n_pages, cfg.n_kv_heads, page_size, row),
+                                dtype),
+            "pool_v": jnp.zeros((n_pages, cfg.n_kv_heads, page_size, row),
+                                dtype),
         }
     return _cache_for(cfg, kind, batch, max_len, dtype)
 
@@ -311,8 +332,9 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int, dtype, *,
                       page_size: int = 64,
                       n_pages: int | None = None) -> dict:
     """Decode cache pytree with paged KV for the full-context attention
-    layers: physical pools ``(n_pages, K, page_size, Dh)`` indexed through
-    the page table that :func:`forward` takes as ``pages``. Ring (local)
+    layers: physical pools ``(n_pages, K, page_size, paged_row(Dh))``,
+    indexed through the page table that :func:`forward` takes as
+    ``pages``. Ring (local)
     and recurrent (ssd/rglru) caches keep their dense layout — they are
     already O(window) / O(1) per slot. Page 0 is reserved as the trash page
     for writes from unbound slots."""
@@ -327,8 +349,7 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int, dtype, *,
             c = _paged_cache_for(cfg, kind, batch, max_len, dtype, **kw)
             if c is not None:
                 per[str(i)] = jax.tree.map(
-                    lambda a: jnp.broadcast_to(
-                        a[None], (cfg.n_periods,) + a.shape).copy(), c)
+                    partial(_stack, cfg.n_periods), c)
         out["periods"] = per
     if cfg.n_remainder:
         tail = {}

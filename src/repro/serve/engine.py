@@ -56,6 +56,27 @@ _RECURRENT_KINDS = ("ssd", "rglru")
 # positional caches are masked by k_valid/page-table logic; only recurrent
 # state carries across steps unmasked and must be zeroed on admission.
 _POSITIONAL_LEAVES = ("k", "v", "pool_k", "pool_v", "c_kv", "k_rope")
+_POOL_LEAVES = ("pool_k", "pool_v")
+
+
+def _keys(path) -> list:
+    return [getattr(p, "key", None) for p in path]
+
+
+def _lane(path, rows) -> tuple:
+    """Index of slot lanes ``rows`` in a per-slot cache leaf: stacked
+    caches lead with the layer axis, the slot axis follows."""
+    return (slice(None),) * ("periods" in _keys(path)) + (rows,)
+
+
+def jit_paged_step(cfg: ModelConfig):
+    """The paged serve step as the engine runs it. It writes and reads each
+    stacked pool in place, and the caches are donated, so the returned pool
+    is the input buffer and no step copies it. The pools' rows fill whole
+    lane tiles (``init_paged_caches``), so the device's default layout is
+    the one the kernel reads and no layout is pinned: a pinned one failed
+    on the chip once executables came from the persistent compile cache."""
+    return jax.jit(make_serve_step(cfg, paged=True), donate_argnums=(2,))
 
 
 @dataclass
@@ -136,7 +157,12 @@ class ServeEngine:
                                                 n_pages=pool_pages)
                 self.allocator: PageAllocator | None = PageAllocator(
                     pool_pages, page_size, n_slots, pages_per_slot)
-                self._serve = jax.jit(make_serve_step(cfg, paged=True))
+                # Donation invalidates the pre-step caches: nothing reads
+                # self.caches between dispatch and apply (reset_full is
+                # dense-only; lazy resets run in assemble, under the lock, by
+                # the single driver), and stalled lanes are restored from a
+                # snapshot taken before dispatch.
+                self._serve = jit_paged_step(cfg)
             else:
                 self.caches = init_caches(cfg, n_slots, max_len, dt)
                 self.allocator = None
@@ -249,31 +275,30 @@ class ServeEngine:
         """Legacy full-tree rebuild (admission="reset_full"): zeroes slot
         ``i``'s lane in *every* cache leaf — O(cache) device work per
         admission, kept as the benchmark baseline for the lazy path."""
-        def zero_lane(path, c):
-            keys = [getattr(p, "key", None) for p in path]
-            bdim = 1 if "periods" in keys else 0  # stacked caches lead with L
-            idx = [slice(None)] * c.ndim
-            idx[bdim] = slice(i, i + 1)
-            return c.at[tuple(idx)].set(0)
-        self.caches = jax.tree_util.tree_map_with_path(zero_lane, self.caches)
+        self.caches = jax.tree_util.tree_map_with_path(
+            lambda p, c: c.at[_lane(p, slice(i, i + 1))].set(0), self.caches)
 
-    def _restore_lanes(self, new: Any, old: Any, idx: list[int]) -> Any:
-        """Copy slot lanes ``idx`` of every per-slot cache leaf from ``old``
-        (the pre-step snapshot) into ``new`` — used to undo the garbage-lane
-        advance of slots that stalled on page-pool exhaustion. Physical page
-        pools are skipped: their leading axis is the page, not the slot, and
-        the cleared table rows already clamped those writes to the trash
-        page."""
+    @staticmethod
+    def _take_lanes(caches: Any, idx: list[int]) -> list:
+        """Slot lanes ``idx`` of every per-slot cache leaf, in leaf order
+        (None for the physical page pools) — the snapshot from which
+        :meth:`_restore_lanes` undoes a stalled slot's garbage-lane advance.
+        Taken before dispatch: the step donates the caches it reads."""
         rows = jnp.asarray(idx, jnp.int32)
+        return [None if _keys(p)[-1] in _POOL_LEAVES else c[_lane(p, rows)]
+                for p, c in jax.tree_util.tree_leaves_with_path(caches)]
 
-        def restore(path, n, o):
-            keys = [getattr(p, "key", None) for p in path]
-            if keys[-1] in ("pool_k", "pool_v"):
-                return n
-            bdim = 1 if "periods" in keys else 0
-            idx_t = (slice(None),) * bdim + (rows,)
-            return n.at[idx_t].set(o[idx_t])
-        return jax.tree_util.tree_map_with_path(restore, new, old)
+    @staticmethod
+    def _restore_lanes(new: Any, lanes: list, idx: list[int]) -> Any:
+        """Write the snapshot ``lanes`` back into slot lanes ``idx`` of
+        ``new``. Physical page pools are skipped: their leading axis is the
+        page, not the slot, and the cleared table rows already clamped those
+        writes to the trash page."""
+        rows = jnp.asarray(idx, jnp.int32)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(new)
+        return jax.tree_util.tree_unflatten(treedef, [
+            n if o is None else n.at[_lane(p, rows)].set(o)
+            for (p, n), o in zip(leaves, lanes)])
 
     def _apply_resets(self) -> None:
         """Zero the state of newly admitted slots, batched across admissions
@@ -294,12 +319,9 @@ class ServeEngine:
         rows = jnp.asarray(idx, jnp.int32)
 
         def zero_lane(path, c):
-            keys = [getattr(p, "key", None) for p in path]
-            if keys[-1] in _POSITIONAL_LEAVES:
+            if _keys(path)[-1] in _POSITIONAL_LEAVES:
                 return c
-            bdim = 1 if "periods" in keys else 0
-            idx_t = (slice(None),) * bdim + (rows,)
-            return c.at[idx_t].set(0)
+            return c.at[_lane(path, rows)].set(0)
         self.caches = jax.tree_util.tree_map_with_path(zero_lane, self.caches)
 
     def _active(self) -> list[int]:
@@ -360,11 +382,15 @@ class ServeEngine:
                     if stalled:
                         # a stalled slot still rides through the device call
                         # as a garbage lane (col=0, pos=0); clearing its row
-                        # makes the K/V scatter clamp to the trash page
+                        # makes the K/V write clamp to the trash page
                         # instead of hitting its real, still-bound
-                        # position-0 page.
+                        # position-0 page. The lane also advances per-slot
+                        # state (recurrent ssd/rglru h/conv, ring K/V at
+                        # index 0): snapshot it before the step donates the
+                        # caches, to roll it back after.
                         table = table.copy()
                         table[stalled] = -1
+                        lanes = self._take_lanes(caches, stalled)
                     pages = jnp.asarray(table)
 
         with phase("serve.dispatch", task_span=False) as dispatch:
@@ -385,11 +411,8 @@ class ServeEngine:
 
         with self._lock, phase("serve.apply", task_span=False):
             if stalled:
-                # the garbage lane also advanced per-slot state (recurrent
-                # ssd/rglru h/conv, ring K/V at index 0) — roll those lanes
-                # back to the pre-step snapshot so a stalled slot resumes
-                # exactly where it paused.
-                new_caches = self._restore_lanes(new_caches, caches, stalled)
+                # a stalled slot resumes exactly where it paused
+                new_caches = self._restore_lanes(new_caches, lanes, stalled)
             self.caches = new_caches
             self.steps += 1
             finished = []

@@ -183,6 +183,36 @@ def test_flash_decode_paged_parity():
     np.testing.assert_allclose(np.asarray(xla), ref, atol=2e-5)
 
 
+@pytest.mark.parametrize("window", [None, 12])
+def test_flash_decode_paged_stacked_reads_its_layer(window):
+    """A stacked (L, P, K, page, D) pool read at layer l gives exactly what
+    the single-layer pool ``pool[l]`` gives, unbound (-1) table entries and
+    a window included, for the Pallas kernel and the XLA lowering alike."""
+    rng = np.random.default_rng(11)
+    n_layers, b, kh, h, dk, ps, pps, npg = 3, 3, 2, 4, 8, 8, 4, 16
+    stack_k = jnp.asarray(rng.standard_normal((n_layers, npg, kh, ps, dk)),
+                          jnp.float32)
+    stack_v = jnp.asarray(rng.standard_normal((n_layers, npg, kh, ps, dk)),
+                          jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, 1, h, dk)), jnp.float32)
+    qpos = jnp.asarray([5, 20, 30], jnp.int32)
+    table = jnp.asarray([[3, -1, -1, -1],
+                         [7, 2, 9, -1],
+                         [1, 4, 12, 15]], jnp.int32)
+    for layer in range(n_layers):
+        one = flash_decode_paged(q, stack_k[layer], stack_v[layer], qpos,
+                                 table, window=window, interpret=True)
+        stacked = flash_decode_paged(q, stack_k, stack_v, qpos, table,
+                                     jnp.int32(layer), window=window,
+                                     interpret=True)
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(one))
+        one = flash_decode_paged_xla(q, stack_k[layer], stack_v[layer], qpos,
+                                     table, window=window)
+        stacked = flash_decode_paged_xla(q, stack_k, stack_v, qpos, table,
+                                         jnp.int32(layer), window=window)
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(one))
+
+
 # ---------------------------------------------------------------------------
 # page allocator
 # ---------------------------------------------------------------------------
@@ -279,6 +309,24 @@ def test_flash_paged_engine_hybrid_arch():
     _drain_and_check(cfg, params, eng, reqs)
 
 
+def test_flash_paged_engine_pages_every_layer():
+    """With max_len inside gemma3's window every attention layer is paged:
+    three pools ride each period's scan carry, stacked, and the two tail
+    layers keep single-layer pools; both read and write in place."""
+    cfg = smoke_config("gemma3_1b")
+    params = init_params(model_spec(cfg), jax.random.PRNGKey(2),
+                         jnp.dtype(cfg.dtype))
+    eng = ServeEngine(cfg, params, n_slots=2, max_len=cfg.window_size,
+                      paged=True, page_size=8, decode_kernel="flash",
+                      kernel_interpret=True)
+    assert all("pool_k" in c for c in eng.caches["periods"].values())
+    assert all(c["pool_k"].ndim == 4 for c in eng.caches["tail"].values())
+    rng = np.random.RandomState(5)
+    reqs = [(f"g{i}", list(rng.randint(0, cfg.vocab_size, 5 + 3 * i)), 4)
+            for i in range(3)]
+    _drain_and_check(cfg, params, eng, reqs)
+
+
 def test_flash_engine_recurrent_arch():
     """recurrentgemma: RG-LRU state must be zeroed on (lazy) admission while
     the ring KV rides the flash kernel's permuted-position path."""
@@ -327,7 +375,8 @@ def test_stalled_slot_resumes_uncorrupted(arch):
     The stalled slot still rides the jitted step as a garbage lane — its
     bound pages, ring KV, and recurrent state must not advance on it, so
     once pages free up it resumes bit-exact against the uninterrupted
-    greedy decode."""
+    greedy decode, on the step that donates the caches it reads (the
+    stalled lanes are snapshot before dispatch)."""
     cfg = smoke_config(arch)
     params = init_params(model_spec(cfg), jax.random.PRNGKey(5),
                          jnp.dtype(cfg.dtype))
@@ -344,7 +393,13 @@ def test_stalled_slot_resumes_uncorrupted(arch):
     done, stalls = {}, 0
     for _ in range(200):
         before = {i: eng.slots[i].position for i in eng._active()}
+        # a pending reset replaces recurrent lanes before the step reads them
+        resetting, steps = bool(eng._pending_reset), eng.steps
+        read = [c for p, c in jax.tree_util.tree_leaves_with_path(eng.caches)
+                if not resetting or p[-1].key in ("k", "v", "pool_k", "pool_v")]
         done.update(eng.step())
+        if eng.steps > steps:  # the step donated the caches it read
+            assert read and all(c.is_deleted() for c in read)
         stalls += sum(1 for i, p in before.items()
                       if not eng.slots[i].done
                       and eng.slots[i].position == p)

@@ -7,7 +7,9 @@ topology is described inside a fixture, never at import: only one process
 at a time may hold the TPU library, and every xdist worker imports this
 file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +17,13 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.kernels import flash_decode as flash_decode_module
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
 from repro.kernels.writhe import writhe_map
+from repro.models import model_spec, param_shapes
+from repro.models.transformer import init_paged_caches, paged_layout
+from repro.serve.engine import jit_paged_step
 
 # (H, K, D): stablelm-1.6b (MHA, head_dim 64) and internlm2-1.8b (GQA 2:1,
 # head_dim 128)
@@ -81,3 +88,75 @@ def test_flash_decode_paged_compiles(one_chip, h, k, d):
     _compile(flash_decode_paged, spec((SLOTS, 1, h, d)), pool, pool,
              spec((SLOTS,), jnp.int32),
              spec((SLOTS, pages_per_slot), jnp.int32))
+
+
+@pytest.mark.parametrize("h,k,d", WIDTHS)
+def test_flash_decode_paged_stacked_compiles(one_chip, h, k, d):
+    """The kernel reads one layer of the stacked (L, P, K, page, D) pool in
+    place, the layer index a scalar-prefetch operand."""
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages_per_slot = CACHE_LEN // PAGE
+    pool = spec((3, SLOTS * pages_per_slot + 1, k, PAGE, d))
+    _compile(flash_decode_paged, spec((SLOTS, 1, h, d)), pool, pool,
+             spec((SLOTS,), jnp.int32),
+             spec((SLOTS, pages_per_slot), jnp.int32), spec((), jnp.int32))
+
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(([^)]*)\)")
+
+
+def test_paged_serve_step_keeps_the_pool_in_place(one_chip, monkeypatch):
+    """The paged serve step, jitted as the engine jits it (stablelm widths,
+    12 slots x 1024, page 64; 2 layers keep the compile short, and any copy
+    would be per layer), moves no pool: no copy, slice, scatter or fusion
+    yields a layer's pool or the stack, each dynamic-update-slice into the
+    stack writes one slot's row, and the donated pool is the output, all
+    in the device's default layouts."""
+    # trace the step as on the chip: the compiled paged kernel
+    monkeypatch.setattr(flash_decode_module, "on_tpu", lambda: True)
+    cfg = get_config("stablelm_1_6b").with_(
+        n_layers=2, dtype="bfloat16", decode_kernel="flash")
+    slots, max_len, page = 12, 1024, 64
+    pages_per_slot, _ = paged_layout(max_len, page, slots)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, param_shapes(model_spec(cfg),
+                                                jnp.bfloat16))
+    caches = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_paged_caches(cfg, slots, max_len, jnp.bfloat16,
+                                  page_size=page)))
+    compiled = jit_paged_step(cfg).lower(
+        params, on_chip(jax.ShapeDtypeStruct((slots, 1), jnp.int32)),
+        caches, on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((slots, pages_per_slot),
+                                     jnp.int32))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the paged kernel, not XLA
+
+    stack = caches["periods"]["0"]["pool_k"].shape
+    pool_shapes = {stack, stack[1:]}
+    row = stack[2] * stack[4]                  # one slot's (K, Dh) row
+    ops = [m.groups() for m in map(_HLO_OP.match, text.splitlines()) if m]
+    shape_of = {name: tuple(int(n) for n in dims.split(",") if n)
+                for name, dims, _, _ in ops}
+    moves = []
+    for name, dims, op, operands in ops:
+        if shape_of[name] not in pool_shapes:
+            continue
+        if op == "dynamic-update-slice" and math.prod(
+                shape_of[operands.split(",")[1].strip()]) <= row:
+            continue                           # one slot's row, in place
+        if op in ("copy", "dynamic-slice", "dynamic-update-slice",
+                  "scatter", "fusion"):
+            moves.append((name, op, shape_of[name]))
+    assert not moves, moves
+    pool_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                     for a in jax.tree.leaves(caches))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < math.prod(stack[1:]) * 2  # < one layer

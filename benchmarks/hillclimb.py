@@ -4,7 +4,7 @@ three chosen cells and prints before/after roofline terms.
 Cells (selection rationale in EXPERIMENTS.md §Perf):
   1. deepseek-v3-671b × decode_32k   — most collective-bound cell
   2. deepseek-v3-671b × train_4k     — worst roofline fraction / HBM violator
-  3. moonshot-v1-16b-a3b × train_4k  — most representative of the EP (expert-
+  3. moonlight-16b-a3b × train_4k  — most representative of the EP (expert-
                                         parallel) substrate of this system
 
 Run:  PYTHONPATH=src python -m benchmarks.hillclimb [--cell N]
@@ -38,16 +38,16 @@ VARIANTS = {
          {"dist_flags": ["fp8_gather", "chunked_ce"],
           "score_dtype": "bfloat16"}, "fp8_cce_bf16s"),
     ],
-    "moonshot_train": [
-        ("moonshot_v1_16b_a3b", "train_4k", {}, ""),
-        ("moonshot_v1_16b_a3b", "train_4k",
+    "moonlight_train": [
+        ("moonlight_16b_a3b", "train_4k", {}, ""),
+        ("moonlight_16b_a3b", "train_4k",
          {"dist_flags": ["chunked_ce"]}, "cce"),
-        ("moonshot_v1_16b_a3b", "train_4k",
+        ("moonlight_16b_a3b", "train_4k",
          {"dist_flags": ["chunked_ce", "fp8_gather"]}, "cce_fp8"),
-        ("moonshot_v1_16b_a3b", "train_4k",
+        ("moonlight_16b_a3b", "train_4k",
          {"dist_flags": ["chunked_ce", "fp8_gather"], "microbatch": 4},
          "cce_fp8_mu4"),
-        ("moonshot_v1_16b_a3b", "train_4k",
+        ("moonlight_16b_a3b", "train_4k",
          {"dist_flags": ["chunked_ce", "fp8_gather"],
           "score_dtype": "bfloat16"}, "cce_fp8_bf16s"),
     ],

@@ -24,7 +24,7 @@ One chip:
 Four chips (``--chips 4``), and nothing else:
 
 3. **sharded step** — ``make_train_step`` of smoke configs (the MoE island
-   of ``moonshot_v1_16b_a3b``, vocab-parallel CE) on a (data=2, model=2)
+   of ``moonlight_16b_a3b``, vocab-parallel CE) on a (data=2, model=2)
    mesh of the four chips against the same step on one chip;
 4. **replicas** — ``ServeReplicaSet`` of 4 ``stablelm_1_6b`` replicas, each
    holding its params and caches on its own chip, against one engine.
@@ -66,7 +66,7 @@ SERVE_NEW_TOKENS = 32
 LOGIT_RTOL = 0.05
 
 # phase 3: sharded step
-SHARDED_ARCHS = ("moonshot_v1_16b_a3b", "stablelm_1_6b")
+SHARDED_ARCHS = ("moonlight_16b_a3b", "stablelm_1_6b")
 LOSS_RTOL = 2e-3
 UPDATE_RTOL = 1e-2
 

@@ -15,7 +15,7 @@ from dataclasses import dataclass
 from repro.models.config import ModelConfig
 
 ARCHS = (
-    "moonshot_v1_16b_a3b",
+    "moonlight_16b_a3b",
     "deepseek_v3_671b",
     "stablelm_1_6b",
     "gemma3_1b",

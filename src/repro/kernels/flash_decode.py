@@ -35,7 +35,11 @@ every layer's pools stacked ``(L, P, K, page_size, D)`` with the layer
 index beside it, and a per-slot page table ``(B, pages_per_slot)`` (-1 =
 unbound) is scalar-prefetched so the k/v ``index_map`` gathers pages
 directly from the stack — no logical cache and no per-layer slice is ever
-materialized.
+materialized. :func:`flash_decode_latent` / :func:`decode_attention_latent`
+run the same kernel body, grid and index map over a latent pool (MLA):
+one KV head for every query head, each page block DMA'd once and read as
+K (the whole row) and V (its first ``dv`` lanes); its ``pallas_call`` is
+named ``flash_decode_latent`` so that a trace tells the two apart.
 
 Every block's last two dims are whole array dims or (8, 128)-aligned, and
 the online-softmax stats are 2-D ``(G, 1)`` VMEM scratch, so both kernels
@@ -285,10 +289,18 @@ def decode_attention(q, k, v, q_positions, k_positions=None, *,
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(qpos_ref, table_ref, layer_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                  window: int | None, page_size: int, n_pages: int):
+def _paged_kernel(qpos_ref, table_ref, layer_ref, q_ref, k_ref, *refs,
+                  scale: float, window: int | None, page_size: int,
+                  n_pages: int, dv: int | None = None):
+    """One (slot, kv head, page) step of the paged decode. ``refs`` are
+    (v, out, m, l, acc); with ``dv`` set the pool is latent and there is
+    no v operand: V is the first ``dv`` lanes of the K block, which is
+    DMA'd once for both."""
     del layer_ref  # read by the k/v index map only
+    if dv is None:
+        v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        o_ref, m_scr, l_scr, acc_scr = refs
     bi = pl.program_id(0)
     pi = pl.program_id(2)
 
@@ -310,8 +322,10 @@ def _paged_kernel(qpos_ref, table_ref, layer_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _body():
-        _attend_block(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], mask,
-                      m_scr, l_scr, acc_scr, scale)
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0] if dv is None else k[:, :dv]
+        _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale)
 
     @pl.when(pi == n_pages - 1)
     def _out():
@@ -320,11 +334,63 @@ def _paged_kernel(qpos_ref, table_ref, layer_ref, q_ref, k_ref, v_ref,
 
 def _stacked(pool_k, pool_v, layer):
     """A single layer's ``(P, K, page_size, D)`` pools as a stack of one
-    (a free reshape) at layer 0; a stack ``(L, P, ...)`` as it is."""
+    (a free reshape) at layer 0; a stack ``(L, P, ...)`` as it is.
+    ``pool_v`` may be None (a latent pool)."""
     if pool_k.ndim == 4:
-        return pool_k[None], pool_v[None], jnp.zeros((), jnp.int32)
+        return (pool_k[None], None if pool_v is None else pool_v[None],
+                jnp.zeros((), jnp.int32))
     assert layer is not None, "a stacked pool needs its layer index"
     return pool_k, pool_v, jnp.asarray(layer, jnp.int32)
+
+
+def _paged_call(q, pool_k, pool_v, q_positions, page_table, layer, *,
+                window, scale, interpret, dv=None, name=None):
+    """The paged decode ``pallas_call`` over a stack ``(L, P, K, page, D)``
+    read at ``layer``. ``pool_v`` None with ``dv``: a latent pool, whose
+    rows are K and whose first ``dv`` lanes are V."""
+    b, sq, h, dk = q.shape
+    assert sq == 1
+    _, _, kh, page_size, _ = pool_k.shape
+    latent = pool_v is None
+    if not latent:
+        dv = pool_v.shape[-1]
+    g = h // kh
+    n_pages = page_table.shape[1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(dk)
+    qt = q[:, 0].reshape(b, kh, g, dk)
+
+    kernel = functools.partial(_paged_kernel, scale=scale, window=window,
+                               page_size=page_size, n_pages=n_pages,
+                               dv=dv if latent else None)
+
+    def kv_map(bi, hi, pi, qp, table, lyr):
+        return (lyr[0], jnp.maximum(table[bi, pi], 0), hi, 0, 0)
+
+    kv_specs = [pl.BlockSpec((None, 1, 1, page_size, dk), kv_map)]
+    pools = [pool_k]
+    if not latent:
+        kv_specs.append(pl.BlockSpec((None, 1, 1, page_size, dv), kv_map))
+        pools.append(pool_v)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, kh, n_pages),
+        in_specs=[
+            pl.BlockSpec((1, 1, g, dk),
+                         lambda bi, hi, pi, qp, tb, ly: (bi, hi, 0, 0)),
+            *kv_specs,
+        ],
+        out_specs=pl.BlockSpec((1, 1, g, dv),
+                               lambda bi, hi, pi, qp, tb, ly: (bi, hi, 0, 0)),
+        scratch_shapes=_stat_scratch(g, dv),
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kh, g, dv), q.dtype),
+        interpret=interpret, name=name,
+    )(q_positions.astype(jnp.int32), page_table.astype(jnp.int32),
+      layer.reshape(1), qt, *pools)
+    return out.reshape(b, 1, h, dv)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
@@ -343,61 +409,53 @@ def flash_decode_paged(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     the stack is read in place; unbound entries clamp to page 0 and are
     masked out by ``page >= 0`` inside the kernel.
     """
-    b, sq, h, dk = q.shape
-    assert sq == 1
     pool_k, pool_v, layer = _stacked(pool_k, pool_v, layer)
-    _, _, kh, page_size, _ = pool_k.shape
-    dv = pool_v.shape[-1]
-    g = h // kh
-    n_pages = page_table.shape[1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(dk)
-    qt = q[:, 0].reshape(b, kh, g, dk)
-
-    kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               page_size=page_size, n_pages=n_pages)
-
-    def kv_map(bi, hi, pi, qp, table, lyr):
-        return (lyr[0], jnp.maximum(table[bi, pi], 0), hi, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, kh, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dk),
-                         lambda bi, hi, pi, qp, tb, ly: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, 1, 1, page_size, dk), kv_map),
-            pl.BlockSpec((None, 1, 1, page_size, dv), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda bi, hi, pi, qp, tb, ly: (bi, hi, 0, 0)),
-        scratch_shapes=_stat_scratch(g, dv),
-    )
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, dv), q.dtype),
-        interpret=interpret,
-    )(q_positions.astype(jnp.int32), page_table.astype(jnp.int32),
-      layer.reshape(1), qt, pool_k, pool_v)
-    return out.reshape(b, 1, h, dv)
+    return _paged_call(q, pool_k, pool_v, q_positions, page_table, layer,
+                       window=window, scale=scale, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "scale", "bounded"))
+@functools.partial(jax.jit, static_argnames=("dv", "scale", "interpret"))
+def flash_decode_latent(q: jax.Array, pool: jax.Array,
+                        q_positions: jax.Array, page_table: jax.Array,
+                        layer: jax.Array | int | None = None, *, dv: int,
+                        scale: float | None = None,
+                        interpret: bool = False) -> jax.Array:
+    """Paged decode against a latent pool (MLA): one KV head whose rows
+    are the keys and whose first ``dv`` lanes are the values.
+
+    q: (B, 1, H, D), every query head in one group; pool: (P, 1,
+    page_size, D) or the stack (L, P, 1, page_size, D) read at ``layer``;
+    page_table as for :func:`flash_decode_paged`. -> (B, 1, H, dv). Each
+    page block is DMA'd once per slot and serves as K and V; the grid,
+    index map and kernel body are :func:`flash_decode_paged`'s.
+    """
+    pool, _, layer = _stacked(pool, None, layer)
+    return _paged_call(q, pool, None, q_positions, page_table, layer,
+                       window=None, scale=scale, interpret=interpret, dv=dv,
+                       name="flash_decode_latent")
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "bounded",
+                                             "dv"))
 def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
-                           pool_v: jax.Array, q_positions: jax.Array,
+                           pool_v: jax.Array | None, q_positions: jax.Array,
                            page_table: jax.Array,
                            layer: jax.Array | int | None = None, *,
                            window: int | None = None,
                            scale: float | None = None,
-                           bounded: bool = True) -> jax.Array:
+                           bounded: bool = True,
+                           dv: int | None = None) -> jax.Array:
     """Paged decode through XLA: a dynamic-trip-count loop over page blocks,
     gathering one physical page of ``layer`` per slot per step. Work scales
-    with bound pages (occupancy), never materializing the logical cache."""
+    with bound pages (occupancy), never materializing the logical cache.
+    ``pool_v`` None with ``dv``: a latent pool, V its first ``dv`` lanes
+    (the contract of :func:`flash_decode_latent`)."""
     b, sq, h, dk = q.shape
     assert sq == 1
     pool_k, pool_v, layer = _stacked(pool_k, pool_v, layer)
     _, _, kh, page_size, _ = pool_k.shape
-    dv = pool_v.shape[-1]
+    if pool_v is not None:
+        dv = pool_v.shape[-1]
     g = h // kh
     n_pages = page_table.shape[1]
     if scale is None:
@@ -414,7 +472,8 @@ def flash_decode_paged_xla(q: jax.Array, pool_k: jax.Array,
         m_run, l_run, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(table, i, 1, axis=1)[:, 0]
         kc = pool_k[layer, jnp.maximum(pages, 0)]   # (B, K, page_size, Dk)
-        vc = pool_v[layer, jnp.maximum(pages, 0)]
+        vc = (kc[..., :dv] if pool_v is None
+              else pool_v[layer, jnp.maximum(pages, 0)])
         kp = i * page_size + jnp.arange(page_size, dtype=jnp.int32)[None, :]
         mask = (pages >= 0)[:, None] & (kp <= qp[:, None])
         if window is not None:
@@ -461,3 +520,20 @@ def decode_attention_paged(q, pool_k, pool_v, q_positions, page_table,
         out = flash_decode_paged_xla(q, pool_k, pool_v, q_positions,
                                      page_table, layer, **kw)
     return out[..., :d]
+
+
+def decode_attention_latent(q, pool, q_positions, page_table, layer=None, *,
+                            dv, scale, interpret=False):
+    """Backend dispatch for a latent pool, by the rule of
+    :func:`decode_attention`. ``q`` (B, 1, H, D) is padded with zeros to
+    the pool's rows, which adds nothing to a score; ``scale`` is the
+    model's."""
+    width = pool.shape[-1]
+    if width != q.shape[-1]:
+        q = jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, width - q.shape[-1])])
+    if on_tpu() or interpret:
+        return flash_decode_latent(q, pool, q_positions, page_table, layer,
+                                   dv=dv, scale=scale,
+                                   interpret=not on_tpu())
+    return flash_decode_paged_xla(q, pool, None, q_positions, page_table,
+                                  layer, scale=scale, dv=dv)

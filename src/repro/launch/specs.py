@@ -77,7 +77,7 @@ def train_knobs(cfg: ModelConfig) -> dict:
     (Gemma-3, InternVL) need µ=16."""
     if cfg.name.startswith("deepseek"):
         return {"remat": "full", "microbatch": 16, "accum_dtype": "bfloat16"}
-    if cfg.name.startswith(("moonshot",)):
+    if cfg.name.startswith(("moonlight",)):
         return {"remat": "full", "microbatch": 8, "accum_dtype": "float32"}
     if cfg.padded_vocab >= 128_000 and cfg.d_model <= 4096:
         return {"remat": "full", "microbatch": 16, "accum_dtype": "float32"}
@@ -179,11 +179,13 @@ def decode_cache_shapes(cfg: ModelConfig, batch: int, max_len: int) -> Any:
 
 
 def reduced_depth(cfg: ModelConfig, n_periods: int) -> ModelConfig:
-    """Same arch at ``n_periods`` scan periods (remainder layers preserved) —
+    """Same arch at ``n_periods`` scan periods (leading dense and remainder
+    layers preserved) —
     the costing-compile trick: FLOPs/bytes/collectives are *exactly* linear in
     the period count, so two shallow unrolled compiles extrapolate to full
     depth (XLA's cost_analysis counts while bodies once; see dryrun.py)."""
-    return cfg.with_(n_layers=cfg.period * n_periods + cfg.n_remainder)
+    return cfg.with_(n_layers=cfg.n_dense_layers + cfg.period * n_periods
+                     + cfg.n_remainder)
 
 
 def make_cell(arch: str, shape: Shape, dist: DistContext, *,

@@ -332,40 +332,21 @@ def _paged_decode(cfg: ModelConfig, q: jax.Array, k: jax.Array,
     stacked ``(L, P, K, page_size, D)`` with this layer's index under
     ``cache["layer"]`` (the scan carries the stack whole, so no layer is
     sliced out or written back). ``D`` is :func:`paged_row` of the head
-    dim; k and v are zero-padded to it. Each slot's new token is written in
-    place into its current page, one ``dynamic_update_slice`` per slot
-    (slots whose table row is unbound clamp to the reserved trash page 0):
-    a scatter would ask for a layout that the kernel cannot read, and XLA
-    would copy the pool to convert. The loop over slots unrolls at trace
-    time, 2·B updates in the one scan body; B is the engine's slot count,
-    fixed per deployment, so the step's HLO grows with it but never with
-    depth or traffic. Attention then reads through the page table. ``decode_kernel="flash"`` uses the fused paged kernel; "chunked"
-    gathers the logical view and runs the reference — pages are bound in
-    logical order, so offsets past a slot's position hold garbage but are
-    causally masked (``k_pos > q_pos``).
+    dim; k and v are zero-padded to it and written in place by
+    :func:`write_paged_rows`. Attention then reads through the page
+    table. ``decode_kernel="flash"`` uses the fused paged kernel;
+    "chunked" gathers the logical view and runs the reference — pages are
+    bound in logical order, so offsets past a slot's position hold garbage
+    but are causally masked (``k_pos > q_pos``).
     """
-    b = q.shape[0]
     dt = q.dtype
     ck, cv = cache["pool_k"], cache["pool_v"]
     layer = cache.get("layer")
-    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
-    cdt = ck.dtype
-    page_size = ck.shape[-2]
-    rows = jnp.arange(b, dtype=jnp.int32)
-    page = pages[rows, positions // page_size]
-    page = jnp.maximum(page, 0)
-    off = positions % page_size
     dk, dv = k.shape[-1], v.shape[-1]
-    k = _pad_last(k, ck.shape[-1])          # rows fill whole lane tiles
-    v = _pad_last(v, cv.shape[-1])
-    zero = jnp.zeros((), jnp.int32)
-    row = (1,) * len(lead) + (1, ck.shape[-3], 1)   # one slot's (K, D) row
-    for i in range(b):
-        at = lead + (page[i], zero, off[i], zero)
-        ck = jax.lax.dynamic_update_slice(
-            ck, k[i, 0].astype(cdt).reshape(row + (ck.shape[-1],)), at)
-        cv = jax.lax.dynamic_update_slice(
-            cv, v[i, 0].astype(cdt).reshape(row + (cv.shape[-1],)), at)
+    ck, cv = write_paged_rows(
+        (ck, cv), (_pad_last(k[:, 0], ck.shape[-1]),  # rows fill whole
+                   _pad_last(v[:, 0], cv.shape[-1])),  # lane tiles
+        positions, pages, layer)
     new_cache = {"pool_k": ck, "pool_v": cv}
     if cfg.decode_kernel == "flash":
         from repro.kernels.flash_decode import decode_attention_paged
@@ -373,24 +354,61 @@ def _paged_decode(cfg: ModelConfig, q: jax.Array, k: jax.Array,
                                      positions, pages, layer, window=window,
                                      interpret=cfg.kernel_interpret)
         return new_cache, out
-    n_pages = pages.shape[1]
-    tbl = jnp.maximum(pages, 0)
-    kh = ck.shape[-3]
-    # (B, n_pages, K, page_size, D) -> logical (B, n_pages*page_size, K, D)
-    k_lin = ck[lead + (tbl,)][..., :dk].transpose(0, 1, 3, 2, 4).reshape(
-        b, n_pages * page_size, kh, dk)
-    v_lin = cv[lead + (tbl,)][..., :dv].transpose(0, 1, 3, 2, 4).reshape(
-        b, n_pages * page_size, kh, dv)
-    kp = (jnp.arange(n_pages, dtype=jnp.int32)[:, None] * page_size +
-          jnp.arange(page_size, dtype=jnp.int32)[None, :])
-    kp = jnp.where(pages[:, :, None] >= 0, kp[None], -1)
-    kp = kp.reshape(b, n_pages * page_size)
-    out = chunked_attention(q, k_lin.astype(dt), v_lin.astype(dt),
+    k_lin, kp = paged_view(ck, pages, layer)
+    v_lin, _ = paged_view(cv, pages, layer)
+    out = chunked_attention(q, k_lin[..., :dk].astype(dt),
+                            v_lin[..., :dv].astype(dt),
                             q_offset=positions, k_positions=kp,
                             causal=True, window=window,
                             kv_chunk=cfg.kv_chunk, k_valid=kp >= 0,
                             score_dtype=jnp.dtype(cfg.score_dtype))
     return new_cache, out
+
+
+def write_paged_rows(pools: tuple, rows: tuple, positions: jax.Array,
+                     pages: jax.Array, layer: jax.Array | None) -> tuple:
+    """Each slot's new row written in place into its current page of each
+    pool: ``pools[j]`` is ``(P, K, page_size, D)`` or the stack ``(L, P,
+    K, page_size, D)`` at ``layer``, ``rows[j]`` is ``(B, K, D)``. One
+    ``dynamic_update_slice`` per slot and pool (slots whose table row is
+    unbound clamp to the reserved trash page 0): a scatter would ask for a
+    layout that the paged kernel cannot read, and XLA would copy the pool
+    to convert. The loop over slots unrolls at trace time, B updates a
+    pool in one scan body; B is the engine's slot count, fixed per
+    deployment, so the step's HLO grows with it but never with depth or
+    traffic."""
+    b, kh, width = rows[0].shape
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
+    page_size = pools[0].shape[-2]
+    slot = jnp.arange(b, dtype=jnp.int32)
+    page = jnp.maximum(pages[slot, positions // page_size], 0)
+    off = positions % page_size
+    zero = jnp.zeros((), jnp.int32)
+    one = (1,) * len(lead) + (1, kh, 1, width)   # one slot's (K, D) row
+    pools = list(pools)
+    for i in range(b):
+        at = lead + (page[i], zero, off[i], zero)
+        for j, r in enumerate(rows):
+            pools[j] = jax.lax.dynamic_update_slice(
+                pools[j], r[i].astype(pools[j].dtype).reshape(one), at)
+    return tuple(pools)
+
+
+def paged_view(pool: jax.Array, pages: jax.Array, layer: jax.Array | None
+               ) -> tuple[jax.Array, jax.Array]:
+    """The logical cache a page table makes of a pool (the reference
+    path's gather): ``(B, n_pages * page_size, K, D)`` and each entry's
+    position, -1 where its page is unbound."""
+    b, n_pages = pages.shape
+    page_size = pool.shape[-2]
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
+    lin = pool[lead + (jnp.maximum(pages, 0),)]     # (B, n, K, page, D)
+    lin = lin.transpose(0, 1, 3, 2, 4).reshape(
+        b, n_pages * page_size, pool.shape[-3], pool.shape[-1])
+    kp = (jnp.arange(n_pages, dtype=jnp.int32)[:, None] * page_size +
+          jnp.arange(page_size, dtype=jnp.int32)[None, :])
+    kp = jnp.where(pages[:, :, None] >= 0, kp[None], -1)
+    return lin, kp.reshape(b, n_pages * page_size)
 
 
 def paged_row(head_dim: int) -> int:

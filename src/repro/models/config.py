@@ -19,6 +19,15 @@ MlpKind = Literal["swiglu", "geglu", "gelu"]
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts and their router, as a published config states them.
+
+    ``scoring`` is how router logits become scores: a softmax over the
+    experts, or an independent sigmoid per expert (DeepSeek-V3's
+    ``scoring_func``), which comes with a learned per-expert correction
+    bias added to the scores for the choice of experts only, never to the
+    gates (``topk_method`` noaux_tc). The chosen scores are renormalised
+    to sum to one (``norm_topk_prob``) and multiplied by
+    ``routed_scaling_factor``."""
     n_experts: int
     top_k: int
     d_expert: int                  # expert FFN hidden dim
@@ -26,12 +35,16 @@ class MoEConfig:
     capacity_factor: float = 1.25  # dispatch capacity (dropped-token bound)
     router_aux_weight: float = 1e-3
     router_dtype: str = "float32"
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scaling_factor: float = 1.0
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V3 Multi-head Latent Attention dims (arXiv:2412.19437)."""
-    q_lora_rank: int = 1536
+    """DeepSeek-V3 Multi-head Latent Attention dims (arXiv:2412.19437).
+    ``q_lora_rank`` None: queries are projected directly, with no
+    low-rank bottleneck (Moonlight, DeepSeek-V2-Lite)."""
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     rope_head_dim: int = 64
     nope_head_dim: int = 128
@@ -78,6 +91,9 @@ class ModelConfig:
     head_dim: int = 0
     d_ff: int = 0
     layer_pattern: tuple[str, ...] = ("attn",)
+    n_dense_layers: int = 0                 # leading "attn" layers with a
+                                            # dense d_ff MLP in an MoE model
+                                            # (first_k_dense_replace)
     window_size: int = 1024                 # for "local" layers
     mlp_kind: MlpKind = "swiglu"
     encoder_only: bool = False              # bidirectional, no decode step
@@ -115,7 +131,9 @@ class ModelConfig:
 
     def layer_kinds(self) -> tuple[str, ...]:
         pat = self.layer_pattern
-        return tuple(pat[i % len(pat)] for i in range(self.n_layers))
+        n = self.n_layers - self.n_dense_layers
+        return ("attn",) * self.n_dense_layers + tuple(
+            pat[i % len(pat)] for i in range(n))
 
     @property
     def period(self) -> int:
@@ -123,11 +141,11 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // self.period
+        return (self.n_layers - self.n_dense_layers) // self.period
 
     @property
     def n_remainder(self) -> int:
-        return self.n_layers % self.period
+        return (self.n_layers - self.n_dense_layers) % self.period
 
     @property
     def q_dim(self) -> int:
@@ -160,13 +178,17 @@ class ModelConfig:
         if not self.tie_embeddings:
             n += d * v
         kinds = self.layer_kinds()
-        for kind in kinds:
+        for li, kind in enumerate(kinds):
             n += 2 * d  # two RMSNorm scales per block
             if kind in ("attn", "local"):
                 if self.mla is not None:
                     m = self.mla
-                    n += d * m.q_lora_rank + m.q_lora_rank  # q down + norm
-                    n += m.q_lora_rank * self.n_heads * (m.nope_head_dim + m.rope_head_dim)
+                    qk = self.n_heads * (m.nope_head_dim + m.rope_head_dim)
+                    if m.q_lora_rank is None:
+                        n += d * qk                          # direct q
+                    else:
+                        n += d * m.q_lora_rank + m.q_lora_rank  # q down + norm
+                        n += m.q_lora_rank * qk
                     n += d * (m.kv_lora_rank + m.rope_head_dim) + m.kv_lora_rank
                     n += m.kv_lora_rank * self.n_heads * (m.nope_head_dim + m.v_head_dim)
                     n += self.n_heads * m.v_head_dim * d
@@ -191,9 +213,11 @@ class ModelConfig:
                 n += w * d                         # out proj
             # MLP
             if kind in ("attn", "local"):
-                if self.moe is not None:
+                if self.moe is not None and li >= self.n_dense_layers:
                     e = self.moe
                     n_router = d * e.n_experts
+                    if e.scoring == "sigmoid":
+                        n_router += e.n_experts  # correction bias
                     per_expert = 3 * d * e.d_expert
                     n += n_router
                     if active_only:

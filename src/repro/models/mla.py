@@ -1,11 +1,15 @@
 """Multi-head Latent Attention (DeepSeek-V3, arXiv:2412.19437).
 
-Queries go through a LoRA bottleneck (``q_lora_rank``); keys/values are
-compressed into a small latent (``kv_lora_rank``) plus one shared RoPE head.
+Queries go through a LoRA bottleneck (``q_lora_rank``), or are projected
+directly when the config has none (Moonlight); keys/values are compressed
+into a small latent (``kv_lora_rank``) plus one shared RoPE head.
 Training/prefill materializes per-head K/V; decode uses the **absorbed**
 formulation — attention runs directly in the compressed latent, so the KV
 cache is ``kv_lora_rank + rope_head_dim`` floats per token *total* (not per
-head), the property that makes 128-head decode at 32k context cheap.
+head), the property that makes 128-head decode at 32k context cheap. The
+serving tier keeps that row in a paged latent pool (``pool_latent``), which
+every query head reads as its one KV head: K is the whole row, V its first
+``kv_lora_rank`` lanes.
 """
 from __future__ import annotations
 
@@ -14,7 +18,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .attention import _expand_positions, chunked_attention
+from .attention import (_expand_positions, _pad_last, chunked_attention,
+                        paged_row, paged_view, write_paged_rows)
 from .config import ModelConfig
 from .layers import apply_rope, rmsnorm
 from .params import ParamSpec
@@ -24,11 +29,20 @@ def mla_spec(cfg: ModelConfig) -> dict:
     m = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
     qk = m.nope_head_dim + m.rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"w_q": ParamSpec((d, h, qk), ("embed", "heads", "head_dim"),
+                              init="lecun")}
+    else:
+        q = {
+            "w_dq": ParamSpec((d, m.q_lora_rank), ("embed", "lora"),
+                              init="lecun"),
+            "q_norm": {"scale": ParamSpec((m.q_lora_rank,), (None,),
+                                          init="ones")},
+            "w_uq": ParamSpec((m.q_lora_rank, h, qk),
+                              ("lora", "heads", "head_dim"), init="lecun"),
+        }
     return {
-        "w_dq": ParamSpec((d, m.q_lora_rank), ("embed", "lora"), init="lecun"),
-        "q_norm": {"scale": ParamSpec((m.q_lora_rank,), (None,), init="ones")},
-        "w_uq": ParamSpec((m.q_lora_rank, h, qk), ("lora", "heads", "head_dim"),
-                          init="lecun"),
+        **q,
         "w_dkv": ParamSpec((d, m.kv_lora_rank + m.rope_head_dim),
                            ("embed", "lora"), init="lecun"),
         "kv_norm": {"scale": ParamSpec((m.kv_lora_rank,), (None,), init="ones")},
@@ -45,8 +59,11 @@ def _project_q(params: dict, cfg: ModelConfig, x: jax.Array,
                positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     """-> (q_nope (B,S,H,nope), q_rope (B,S,H,rope))."""
     m = cfg.mla
-    cq = rmsnorm(params["q_norm"], x @ params["w_dq"], cfg.rms_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
+    if "w_q" in params:
+        q = jnp.einsum("bsd,dhk->bshk", x, params["w_q"])
+    else:
+        cq = rmsnorm(params["q_norm"], x @ params["w_dq"], cfg.rms_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
     q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope
@@ -67,8 +84,11 @@ def mla_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
               positions: jax.Array | int = 0,
               cache: dict | None = None,
               cache_index: jax.Array | None = None,
-              dist=None) -> tuple[jax.Array, dict | None]:
-    """MLA attention block. ``cache``: {"c_kv": (B, S, R), "k_rope": (B, S, rope)}."""
+              dist=None,
+              pages: jax.Array | None = None) -> tuple[jax.Array, dict | None]:
+    """MLA attention block. ``cache``: {"c_kv": (B, S, R), "k_rope": (B, S,
+    rope)}, or a paged {"pool_latent": ...} with the page table ``pages``
+    (see :func:`_paged_latent_decode`)."""
     m = cfg.mla
     b, s, _ = x.shape
     dt = x.dtype
@@ -94,6 +114,14 @@ def mla_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
     # absorbed decode: attention in the compressed latent.
     assert cache_index is not None
     cache_index = jnp.asarray(cache_index, jnp.int32)
+    if "pool_latent" in cache:  # paged latent cache (serving tier)
+        assert cache_index.ndim == 1 and s == 1 and pages is not None
+        new_cache, ctx = _paged_latent_decode(params, cfg, q_nope, q_rope,
+                                              c_kv, k_rope, cache,
+                                              cache_index, pages, scale)
+        y = jnp.einsum("bshr,rhk,hkd->bsd", ctx, params["w_uv"].astype(dt),
+                       params["w_o"].astype(dt))
+        return y, new_cache
     cdt = cache["c_kv"].dtype
     if cache_index.ndim == 1:  # continuous batching: per-slot positions
         rows = jnp.arange(b, dtype=jnp.int32)[:, None]
@@ -138,9 +166,60 @@ def mla_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
     return y, new_cache
 
 
+def _paged_latent_decode(params: dict, cfg: ModelConfig, q_nope, q_rope,
+                         c_kv, k_rope, cache: dict, positions: jax.Array,
+                         pages: jax.Array, scale: float
+                         ) -> tuple[dict, jax.Array]:
+    """One absorbed decode step against a paged latent pool: one layer's
+    ``(P, 1, page_size, D)`` or the stack ``(L, P, 1, page_size, D)`` at
+    ``cache["layer"]``, D the row ``[c_kv | k_rope]`` rounded up to whole
+    lane tiles (``paged_row``). Each slot's row is written in place into
+    its page (:func:`write_paged_rows`); the absorbed query
+    ``[q_nope @ w_uk | q_rope]`` of every head attends over the rows, the
+    values being their first ``kv_lora_rank`` lanes. -> (new cache, context
+    (B, 1, H, kv_lora_rank))."""
+    m = cfg.mla
+    dt = q_nope.dtype
+    pool = cache["pool_latent"]
+    layer = cache.get("layer")
+    row = jnp.concatenate([c_kv[:, 0], k_rope[:, 0, 0]], axis=-1)
+    row = _pad_last(row, pool.shape[-1])[:, None]          # (B, 1, D)
+    (pool,) = write_paged_rows((pool,), (row,), positions, pages, layer)
+    new_cache = {"pool_latent": pool}
+    q_eff = jnp.einsum("bshk,rhk->bshr", q_nope, params["w_uk"].astype(dt))
+    q_cat = jnp.concatenate([q_eff, q_rope], axis=-1)     # (B, 1, H, R+rope)
+    r = m.kv_lora_rank
+    if cfg.decode_kernel == "flash":
+        from repro.kernels.flash_decode import decode_attention_latent
+        ctx = decode_attention_latent(q_cat.astype(dt), pool.astype(dt),
+                                      positions, pages, layer, dv=r,
+                                      scale=scale,
+                                      interpret=cfg.kernel_interpret)
+        return new_cache, ctx
+    lin, kp = paged_view(pool, pages, layer)               # (B, S, 1, D)
+    ctx = chunked_attention(q_cat.astype(dt),
+                            lin[..., :r + m.rope_head_dim].astype(dt),
+                            lin[..., :r].astype(dt), q_offset=positions,
+                            k_positions=kp, causal=True,
+                            kv_chunk=cfg.kv_chunk, k_valid=kp >= 0,
+                            scale=scale,
+                            score_dtype=jnp.dtype(cfg.score_dtype))
+    return new_cache, ctx
+
+
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
     m = cfg.mla
     return {
         "c_kv": jnp.zeros((batch, max_len, m.kv_lora_rank), dtype),
         "k_rope": jnp.zeros((batch, max_len, m.rope_head_dim), dtype),
     }
+
+
+def init_paged_mla_cache(cfg: ModelConfig, n_pages: int, page_size: int,
+                         dtype) -> dict:
+    """One layer's paged latent pool, ``(n_pages, 1, page_size, D)`` with
+    D the latent row ``kv_lora_rank + rope_head_dim`` rounded up to whole
+    128-lane tiles, so that the TPU's default layout is row-major."""
+    m = cfg.mla
+    row = paged_row(m.kv_lora_rank + m.rope_head_dim)
+    return {"pool_latent": jnp.zeros((n_pages, 1, page_size, row), dtype)}

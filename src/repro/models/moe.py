@@ -15,8 +15,10 @@ Two numerically-matching implementations:
   collective footprint as Megatron TP). With ``e0=0, n_local=E`` it is the
   single-device implementation.
 
-Router: softmax over experts in fp32, top-k, gates renormalized over the
-selected experts; Switch-style load-balancing auxiliary loss.
+Router (fp32): a softmax over the experts, or DeepSeek-V3's independent
+sigmoid per expert with a correction bias that only the choice of the top-k
+sees; gates are the chosen scores, renormalised over the selected experts
+and scaled as the config states; Switch-style load-balancing auxiliary loss.
 """
 from __future__ import annotations
 
@@ -42,6 +44,9 @@ def moe_spec(cfg: ModelConfig) -> dict:
         "w_down": ParamSpec((e.n_experts, e.d_expert, d),
                             ("experts", "expert_ff", "embed"), init="lecun"),
     }
+    if e.scoring == "sigmoid":
+        spec["router_bias"] = ParamSpec((e.n_experts,), (None,),
+                                        init="normal", scale=0.1)
     if e.n_shared:
         f = e.n_shared * e.d_expert
         spec["shared"] = {
@@ -54,12 +59,24 @@ def moe_spec(cfg: ModelConfig) -> dict:
 
 def router_topk(params: dict, cfg: ModelConfig, x: jax.Array
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """x: (T, d) -> (gates (T, k) f32, idx (T, k) i32, aux_loss scalar)."""
+    """x: (T, d) -> (gates (T, k) f32, idx (T, k) i32, aux_loss scalar).
+
+    ``params``: "router" (d, E) and, with sigmoid scoring, "router_bias"
+    (E,), which is added to the scores for the choice alone."""
     e = cfg.moe
     logits = (x.astype(jnp.float32) @ params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
-    gates, idx = jax.lax.top_k(probs, e.top_k)                  # (T, k)
+    if e.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)                         # (T, E)
+        choice = scores + params["router_bias"].astype(jnp.float32)
+        _, idx = jax.lax.top_k(choice, e.top_k)                 # (T, k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+        probs = scores / scores.sum(-1, keepdims=True)          # for aux
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)                 # (T, E)
+        gates, idx = jax.lax.top_k(probs, e.top_k)              # (T, k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if e.routed_scaling_factor != 1.0:
+        gates = gates * e.routed_scaling_factor
     # Switch load-balance loss: E * sum_e f_e * P_e
     t = x.shape[0]
     counts = jnp.zeros((e.n_experts,), jnp.float32).at[idx.reshape(-1)].add(1.0)

@@ -6,7 +6,9 @@ repeating *period* (e.g. Gemma-3's ``(local×5, global)``; RecurrentGemma's
 for the full periods are stacked on a leading ``layers`` axis so the lowered
 HLO contains **one** period body regardless of depth — this is what keeps
 dry-run compiles of 61-layer models tractable and the compiled program small.
-Remainder layers (``n_layers % period``) are applied unrolled after the scan.
+Remainder layers (``n_layers % period``) are applied unrolled after the scan;
+an MoE model's leading dense layers (``n_dense_layers``, DeepSeek-V3's
+``first_k_dense_replace``) unrolled before it, under ``"lead"``.
 
 ``dist`` (a ``repro.sharding.DistContext`` or None) switches the MoE between
 the single-device capacity path and the expert-parallel ``shard_map`` island.
@@ -23,7 +25,7 @@ import jax.numpy as jnp
 from .attention import attention_block, attention_spec, init_kv_cache, paged_row
 from .config import ModelConfig
 from .layers import embed, embedding_spec, mlp, mlp_spec, rmsnorm, rmsnorm_spec, unembed
-from .mla import init_mla_cache, mla_block, mla_spec
+from .mla import init_mla_cache, init_paged_mla_cache, mla_block, mla_spec
 from .moe import moe_block, moe_spec
 from .params import ParamSpec, stack_specs
 from .rglru import init_rglru_cache, rglru_block, rglru_spec
@@ -36,7 +38,15 @@ def _has_mlp(cfg: ModelConfig, kind: str) -> bool:
     return cfg.d_ff > 0
 
 
-def block_spec(cfg: ModelConfig, kind: str) -> dict:
+# cache leaves that are physical page pools (see init_paged_caches): their
+# leading axis, after a stack's layer axis, is the page and not the slot,
+# and the layer scan carries them whole
+POOL_LEAVES = ("pool_k", "pool_v", "pool_latent")
+
+
+def block_spec(cfg: ModelConfig, kind: str, dense: bool = False) -> dict:
+    """One residual block's parameters. ``dense``: a leading dense layer
+    of an MoE model, whose FFN is the d_ff MLP."""
     d = cfg.d_model
     spec: dict = {"norm1": rmsnorm_spec(d)}
     if kind in ("attn", "local"):
@@ -49,7 +59,8 @@ def block_spec(cfg: ModelConfig, kind: str) -> dict:
         raise ValueError(f"unknown layer kind {kind}")
     if _has_mlp(cfg, kind):
         spec["norm2"] = rmsnorm_spec(d)
-        spec["ffn"] = moe_spec(cfg) if cfg.moe is not None else mlp_spec(cfg)
+        spec["ffn"] = (moe_spec(cfg) if cfg.moe is not None and not dense
+                       else mlp_spec(cfg))
     return spec
 
 
@@ -59,15 +70,21 @@ def block_apply(params: dict, cfg: ModelConfig, kind: str, x: jax.Array, *,
                 cache_index: jax.Array | None = None,
                 dist: Any = None,
                 decode: bool = False,
-                pages: jax.Array | None = None) -> tuple[jax.Array, dict | None, jax.Array]:
-    """One residual block. Returns (x, new_cache, aux_loss)."""
+                pages: jax.Array | None = None,
+                dense: bool = False) -> tuple[jax.Array, dict | None, jax.Array]:
+    """One residual block. Returns (x, new_cache, aux_loss). ``dense``: a
+    leading dense layer (its FFN is the d_ff MLP even in an MoE model).
+    Latent attention and routed experts run under the named scopes "mla"
+    and "moe", so that a profile attributes their fused ops."""
     aux = jnp.zeros((), jnp.float32)
     h = rmsnorm(params["norm1"], x, cfg.rms_eps)
     if kind in ("attn", "local"):
         if cfg.mla is not None:
-            y, new_cache = mla_block(params["mix"], cfg, h,
-                                     positions=positions, cache=cache,
-                                     cache_index=cache_index, dist=dist)
+            with jax.named_scope("mla"):
+                y, new_cache = mla_block(params["mix"], cfg, h,
+                                         positions=positions, cache=cache,
+                                         cache_index=cache_index, dist=dist,
+                                         pages=pages)
         else:
             y, new_cache = attention_block(params["mix"], cfg, h, kind=kind,
                                            positions=positions, cache=cache,
@@ -80,12 +97,14 @@ def block_apply(params: dict, cfg: ModelConfig, kind: str, x: jax.Array, *,
     x = x + y
     if _has_mlp(cfg, kind):
         h = rmsnorm(params["norm2"], x, cfg.rms_eps)
-        if cfg.moe is not None:
-            if dist is not None:
-                f, aux = dist.moe_island(params["ffn"], cfg, h, decode=decode)
-            else:
-                f, aux = moe_block(params["ffn"], cfg, h, impl="capacity",
-                                   dropless=decode)
+        if cfg.moe is not None and not dense:
+            with jax.named_scope("moe"):
+                if dist is not None:
+                    f, aux = dist.moe_island(params["ffn"], cfg, h,
+                                             decode=decode)
+                else:
+                    f, aux = moe_block(params["ffn"], cfg, h,
+                                       impl="capacity", dropless=decode)
         else:
             f = mlp(params["ffn"], cfg, h)
         x = x + f
@@ -106,6 +125,9 @@ def model_spec(cfg: ModelConfig) -> dict:
         "embed": embedding_spec(cfg),
         "final_norm": rmsnorm_spec(cfg.d_model),
     }
+    if cfg.n_dense_layers:
+        spec["lead"] = {str(i): block_spec(cfg, "attn", dense=True)
+                        for i in range(cfg.n_dense_layers)}
     if cfg.n_periods > 0:
         spec["periods"] = stack_specs(period_spec, cfg.n_periods)
     if cfg.n_remainder:
@@ -143,6 +165,22 @@ def _apply_period(params_p: dict, cfg: ModelConfig, x: jax.Array, *,
     return x, new_caches, aux
 
 
+def _apply_unrolled(params_u: dict, cfg: ModelConfig, kinds, x, *,
+                    caches_u, dense: bool = False, **kw):
+    """Blocks applied one by one (the lead or the tail of the stack):
+    ``params_u``/``caches_u`` hold block ``str(i)`` of ``kinds``."""
+    new_caches = {}
+    aux = jnp.zeros((), jnp.float32)
+    for i, kind in enumerate(kinds):
+        c = caches_u.get(str(i)) if caches_u is not None else None
+        x, nc, a = block_apply(params_u[str(i)], cfg, kind, x, cache=c,
+                               dense=dense, **kw)
+        aux = aux + a
+        if nc is not None:
+            new_caches[str(i)] = nc
+    return x, new_caches, aux
+
+
 def forward(params: dict, cfg: ModelConfig, batch: dict, *,
             caches: dict | None = None,
             cache_index: jax.Array | None = None,
@@ -156,7 +194,8 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
 
     ``batch``: {"tokens": (B, S) int32} and/or {"embeds": (B, S, input_dim)}
     for stub frontends; VLM concatenates projected patch embeds before text.
-    ``caches``: {"periods": stacked-cache pytree, "tail": {...}} or None.
+    ``caches``: {"lead": {...}, "periods": stacked-cache pytree,
+    "tail": {...}} or None.
     ``pages``: (B, pages_per_slot) int32 page table when ``caches`` came
     from :func:`init_paged_caches` (shared by every paged layer — slot
     positions advance uniformly across the stack).
@@ -187,6 +226,16 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
     positions: jax.Array | int = cache_index if decode else 0
     aux_total = jnp.zeros((), jnp.float32)
     new_caches: dict = {}
+    kw = dict(positions=positions, cache_index=cache_index, dist=dist,
+              decode=decode, pages=pages)
+
+    if cfg.n_dense_layers:
+        x, new_lead, aux_total = _apply_unrolled(
+            params["lead"], cfg, ("attn",) * cfg.n_dense_layers, x,
+            caches_u=caches.get("lead") if decode else None, dense=True,
+            **kw)
+        if decode:
+            new_caches["lead"] = new_lead
 
     if cfg.n_periods > 0:
         params_p = params["periods"]
@@ -195,7 +244,8 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
         # each layer writes and reads its own slice in place: as scan xs/ys
         # every layer's pool would be sliced out, relaid and written back.
         # Every other cache leaf is scanned as before.
-        pools = {i: c for i, c in (caches_p or {}).items() if "pool_k" in c}
+        pools = {i: c for i, c in (caches_p or {}).items()
+                 if any(k in c for k in POOL_LEAVES)}
         layer_idx = None
         if pools:
             caches_p = {i: c for i, c in caches_p.items() if i not in pools}
@@ -228,18 +278,10 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
             new_caches["periods"] = {**nc_stack, **pools}
 
     if cfg.n_remainder:
-        caches_t = caches.get("tail") if decode else None
-        new_tail = {}
-        for i in range(cfg.n_remainder):
-            kind = cfg.layer_pattern[i]
-            c = caches_t.get(str(i)) if caches_t is not None else None
-            x, nc, a = block_apply(params["tail"][str(i)], cfg, kind, x,
-                                   positions=positions, cache=c,
-                                   cache_index=cache_index, dist=dist,
-                                   decode=decode, pages=pages)
-            aux_total = aux_total + a
-            if nc is not None:
-                new_tail[str(i)] = nc
+        x, new_tail, a = _apply_unrolled(
+            params["tail"], cfg, cfg.layer_pattern[:cfg.n_remainder], x,
+            caches_u=caches.get("tail") if decode else None, **kw)
+        aux_total = aux_total + a
         if decode:
             new_caches["tail"] = new_tail
 
@@ -278,26 +320,33 @@ def _stack(n: int, a: jax.Array) -> jax.Array:
     return jnp.broadcast_to(a[None], (n,) + a.shape)
 
 
+def _cache_tree(cfg: ModelConfig, make) -> dict:
+    """The decode cache pytree matching the layout of :func:`forward`,
+    ``make(kind)`` giving one layer's cache (or None): the lead layers'
+    own, each period layer's stacked for the scan, the tail's own."""
+    def caches(kinds) -> dict:
+        out = {}
+        for i, kind in enumerate(kinds):
+            c = make(kind)
+            if c is not None:
+                out[str(i)] = c
+        return out
+
+    out: dict = {}
+    if cfg.n_dense_layers:
+        out["lead"] = caches(("attn",) * cfg.n_dense_layers)
+    if cfg.n_periods > 0:
+        out["periods"] = jax.tree.map(partial(_stack, cfg.n_periods),
+                                      caches(cfg.layer_pattern))
+    if cfg.n_remainder:
+        out["tail"] = caches(cfg.layer_pattern[:cfg.n_remainder])
+    return out
+
+
 def init_caches(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
     """Decode cache pytree matching the scan layout of :func:`forward`."""
-    out: dict = {}
-    if cfg.n_periods > 0:
-        per = {}
-        for i, kind in enumerate(cfg.layer_pattern):
-            c = _cache_for(cfg, kind, batch, max_len, dtype)
-            if c is not None:
-                per[str(i)] = jax.tree.map(
-                    partial(_stack, cfg.n_periods), c)
-        out["periods"] = per
-    if cfg.n_remainder:
-        tail = {}
-        for i in range(cfg.n_remainder):
-            kind = cfg.layer_pattern[i]
-            c = _cache_for(cfg, kind, batch, max_len, dtype)
-            if c is not None:
-                tail[str(i)] = c
-        out["tail"] = tail
-    return out
+    return _cache_tree(
+        cfg, lambda kind: _cache_for(cfg, kind, batch, max_len, dtype))
 
 
 def paged_layout(max_len: int, page_size: int, batch: int,
@@ -318,6 +367,8 @@ def _paged_cache_for(cfg: ModelConfig, kind: str, batch: int, max_len: int,
         if kind == "local" and min(max_len, cfg.window_size) < max_len:
             # ring buffers are already O(window); keep them dense.
             return init_kv_cache(cfg, kind, batch, max_len, dtype)
+        if cfg.mla is not None:
+            return init_paged_mla_cache(cfg, n_pages, page_size, dtype)
         row = paged_row(cfg.head_dim)
         return {
             "pool_k": jnp.zeros((n_pages, cfg.n_kv_heads, page_size, row),
@@ -332,34 +383,17 @@ def init_paged_caches(cfg: ModelConfig, batch: int, max_len: int, dtype, *,
                       page_size: int = 64,
                       n_pages: int | None = None) -> dict:
     """Decode cache pytree with paged KV for the full-context attention
-    layers: physical pools ``(n_pages, K, page_size, paged_row(Dh))``,
-    indexed through the page table that :func:`forward` takes as
-    ``pages``. Ring (local)
+    layers: physical pools ``(n_pages, K, page_size, paged_row(Dh))``, or
+    for latent attention (MLA) one latent pool ``(n_pages, 1, page_size,
+    paged_row(kv_lora_rank + rope_head_dim))`` per layer, indexed through
+    the page table that :func:`forward` takes as ``pages``. Ring (local)
     and recurrent (ssd/rglru) caches keep their dense layout — they are
     already O(window) / O(1) per slot. Page 0 is reserved as the trash page
     for writes from unbound slots."""
-    if cfg.mla is not None:
-        raise NotImplementedError("paged KV cache with MLA latent caches")
     _, n_pages = paged_layout(max_len, page_size, batch, n_pages)
-    kw = dict(page_size=page_size, n_pages=n_pages)
-    out: dict = {}
-    if cfg.n_periods > 0:
-        per = {}
-        for i, kind in enumerate(cfg.layer_pattern):
-            c = _paged_cache_for(cfg, kind, batch, max_len, dtype, **kw)
-            if c is not None:
-                per[str(i)] = jax.tree.map(
-                    partial(_stack, cfg.n_periods), c)
-        out["periods"] = per
-    if cfg.n_remainder:
-        tail = {}
-        for i in range(cfg.n_remainder):
-            kind = cfg.layer_pattern[i]
-            c = _paged_cache_for(cfg, kind, batch, max_len, dtype, **kw)
-            if c is not None:
-                tail[str(i)] = c
-        out["tail"] = tail
-    return out
+    return _cache_tree(cfg, lambda kind: _paged_cache_for(
+        cfg, kind, batch, max_len, dtype, page_size=page_size,
+        n_pages=n_pages))
 
 
 def cache_shapes(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Any:

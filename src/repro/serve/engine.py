@@ -45,8 +45,8 @@ import numpy as np
 
 from repro.core import ClusterComputing, register_script
 from repro.models.config import ModelConfig
-from repro.models.transformer import (init_caches, init_paged_caches,
-                                      paged_layout)
+from repro.models.transformer import (POOL_LEAVES, init_caches,
+                                      init_paged_caches, paged_layout)
 from repro.obs import phase
 from repro.train.step import make_serve_step
 
@@ -55,8 +55,7 @@ from .paged import PageAllocator
 _RECURRENT_KINDS = ("ssd", "rglru")
 # positional caches are masked by k_valid/page-table logic; only recurrent
 # state carries across steps unmasked and must be zeroed on admission.
-_POSITIONAL_LEAVES = ("k", "v", "pool_k", "pool_v", "c_kv", "k_rope")
-_POOL_LEAVES = ("pool_k", "pool_v")
+_POSITIONAL_LEAVES = ("k", "v", "c_kv", "k_rope") + POOL_LEAVES
 
 
 def _keys(path) -> list:
@@ -65,7 +64,8 @@ def _keys(path) -> list:
 
 def _lane(path, rows) -> tuple:
     """Index of slot lanes ``rows`` in a per-slot cache leaf: stacked
-    caches lead with the layer axis, the slot axis follows."""
+    ("periods") caches lead with the layer axis, the slot axis follows;
+    the lead and tail layers' own caches lead with the slot axis."""
     return (slice(None),) * ("periods" in _keys(path)) + (rows,)
 
 
@@ -285,7 +285,7 @@ class ServeEngine:
         :meth:`_restore_lanes` undoes a stalled slot's garbage-lane advance.
         Taken before dispatch: the step donates the caches it reads."""
         rows = jnp.asarray(idx, jnp.int32)
-        return [None if _keys(p)[-1] in _POOL_LEAVES else c[_lane(p, rows)]
+        return [None if _keys(p)[-1] in POOL_LEAVES else c[_lane(p, rows)]
                 for p, c in jax.tree_util.tree_leaves_with_path(caches)]
 
     @staticmethod

@@ -132,7 +132,7 @@ class DistContext:
                                           tiled=True).astype(w.dtype)
             return jax.lax.all_gather(w, fsdp, axis=axis, tiled=True)
 
-        def island(router, w_gate, w_up, w_down, xl):
+        def island(route, w_gate, w_up, w_down, xl):
             if d_sh is not None:
                 w_gate = gathered(w_gate, 1)
                 w_up = gathered(w_up, 1)
@@ -143,7 +143,7 @@ class DistContext:
                   else 0)
             flat = xl.reshape(-1, d)
             y, aux = moe_capacity(
-                {"router": router, "w_gate": w_gate, "w_up": w_up,
+                {**route, "w_gate": w_gate, "w_up": w_up,
                  "w_down": w_down_g}, cfg, flat,
                 e0=e0, n_local=n_local, capacity=capacity)
             y = jax.lax.psum(y, tp)
@@ -157,10 +157,9 @@ class DistContext:
         wd_spec = P(expert_sh, None, d_sh)
         y, aux = shard_map(
             island, mesh=self.mesh,
-            in_specs=(P(None, None), w_spec, w_spec, wd_spec,
-                      P(bax, None, None)),
+            in_specs=(P(), w_spec, w_spec, wd_spec, P(bax, None, None)),
             out_specs=(P(bax, None, None), P()),
-        )(params["router"], params["w_gate"], params["w_up"],
+        )(_route(params), params["w_gate"], params["w_up"],
           params["w_down"], x)
         if e.n_shared:
             y = y + shared_expert(params, cfg, x.reshape(-1, d)).reshape(x.shape)
@@ -184,14 +183,13 @@ class DistContext:
         n_fsdp = _size(self.mesh, fsdp)
         d_local = d // n_fsdp if d_sh is not None else d
 
-        def island(router, w_gate, w_up, w_down, xl):
+        def island(route, w_gate, w_up, w_down, xl):
             # gather all tokens (decode: a few hundred rows) over FSDP axes
             flat = xl.reshape(-1, d)
             xg = (jax.lax.all_gather(flat, fsdp, axis=0, tiled=True)
                   if _axes_of(bax) else flat)
             t_g = xg.shape[0]
-            gates, idx, aux = router_topk(
-                {"router": router}, cfg, xg)
+            gates, idx, aux = router_topk(route, cfg, xg)
             e0 = (jax.lax.axis_index(tp) * n_local if expert_sh is not None
                   else 0)
             if d_sh is not None:
@@ -260,10 +258,9 @@ class DistContext:
         wd_spec = P(expert_sh, None, d_sh)
         y, aux = shard_map(
             island, mesh=self.mesh,
-            in_specs=(P(None, None), w_spec, w_spec, wd_spec,
-                      P(bax, None, None)),
+            in_specs=(P(), w_spec, w_spec, wd_spec, P(bax, None, None)),
             out_specs=(P(bax, None, None), P()),
-        )(params["router"], params["w_gate"], params["w_up"],
+        )(_route(params), params["w_gate"], params["w_up"],
           params["w_down"], x)
         if cfg.moe.n_shared:
             from repro.models.moe import shared_expert
@@ -451,6 +448,12 @@ class DistContext:
         )(logits, labels, weights)
         loss = ce + z_weight * z
         return loss, {"ce": ce, "z_loss": z, "tokens": denom}
+
+
+def _route(params: dict) -> dict:
+    """The router's parameters of an MoE layer: its weight and, where the
+    config has one, its score-correction bias. Replicated in the islands."""
+    return {k: params[k] for k in ("router", "router_bias") if k in params}
 
 
 def _size(mesh: Mesh, axes: tuple[str, ...] | str | None) -> int:

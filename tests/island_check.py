@@ -16,7 +16,7 @@ UPDATE_RTOL = 1e-2
 
 
 def main():
-    archs = sys.argv[1:] or ["moonshot_v1_16b_a3b", "gemma3_1b",
+    archs = sys.argv[1:] or ["moonlight_16b_a3b", "gemma3_1b",
                              "mamba2_130m", "recurrentgemma_2b",
                              "deepseek_v3_671b", "hubert_xlarge",
                              "internvl2_1b", "stablelm_1_6b"]

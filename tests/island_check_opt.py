@@ -39,7 +39,7 @@ def check_train_chunked_ce(mesh, arch="gemma3_1b"):
 
 
 def check_fp8_gather_moe(mesh):
-    cfg = smoke_config("moonshot_v1_16b_a3b")
+    cfg = smoke_config("moonlight_16b_a3b")
     ocfg = OptimizerConfig(lr=1e-2, warmup_steps=0, schedule="constant", weight_decay=0.0)
     rng = np.random.RandomState(1)
     batch = {"tokens": jnp.asarray(rng.randint(0, cfg.vocab_size, (4, 32)), jnp.int32),

@@ -102,7 +102,7 @@ def test_full_config_instantiates_abstractly(arch):
     spec = model_spec(cfg)
     n = count_params(spec)
     expected = {
-        "moonshot_v1_16b_a3b": (20e9, 35e9),
+        "moonlight_16b_a3b": (15e9, 17e9),
         "deepseek_v3_671b": (600e9, 720e9),
         "stablelm_1_6b": (1.2e9, 2.2e9),
         "gemma3_1b": (0.7e9, 1.5e9),

@@ -28,7 +28,7 @@ def test_sharded_matches_single_device_all_families():
 
 def test_sharded_matches_single_device_moe():
     r = _run([str(ROOT / "tests" / "island_check.py"),
-              "moonshot_v1_16b_a3b"])
+              "moonlight_16b_a3b"])
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
 
 

@@ -19,7 +19,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import flash_decode as flash_decode_module
-from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.flash_decode import (flash_decode, flash_decode_latent,
+                                        flash_decode_paged)
 from repro.kernels.writhe import writhe_map
 from repro.models import model_spec, param_shapes
 from repro.models.transformer import init_paged_caches, paged_layout
@@ -104,6 +105,23 @@ def test_flash_decode_paged_stacked_compiles(one_chip, h, k, d):
              spec((SLOTS, pages_per_slot), jnp.int32), spec((), jnp.int32))
 
 
+def test_flash_decode_latent_compiles(one_chip):
+    """The latent kernel at Moonlight's widths: 16 query heads over one
+    latent head, rows of 576 lanes padded to 640, values the first 512,
+    read from a stacked pool at its layer."""
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages_per_slot = 1024 // PAGE
+    pool = spec((6, 64 * pages_per_slot + 1, 1, PAGE, 640))
+    compiled = _compile(
+        lambda q, p, qp, t, l: flash_decode_latent(
+            q, p, qp, t, l, dv=512, scale=1 / math.sqrt(192)),
+        spec((64, 1, 16, 640)), pool, spec((64,), jnp.int32),
+        spec((64, pages_per_slot), jnp.int32), spec((), jnp.int32))
+    assert "flash_decode_latent" in compiled.as_text()
+
+
 _HLO_OP = re.compile(r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*)\]\S* "
                      r"([\w-]+)\(([^)]*)\)")
 
@@ -137,11 +155,20 @@ def test_paged_serve_step_keeps_the_pool_in_place(one_chip, monkeypatch):
                                      jnp.int32))).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the paged kernel, not XLA
+    _assert_pool_in_place(compiled, caches["periods"]["0"]["pool_k"].shape,
+                          caches)
 
-    stack = caches["periods"]["0"]["pool_k"].shape
+
+def _assert_pool_in_place(compiled, stack, caches):
+    """No copy, slice, scatter or fusion of the compiled step yields a
+    layer's pool or the stack ``(L, P, K, page, D)``, each
+    dynamic-update-slice into one writes one slot's row, the donated
+    caches are aliased to the output, and the step's temporaries are
+    smaller than one layer's pool."""
     pool_shapes = {stack, stack[1:]}
-    row = stack[2] * stack[4]                  # one slot's (K, Dh) row
-    ops = [m.groups() for m in map(_HLO_OP.match, text.splitlines()) if m]
+    row = stack[2] * stack[4]                  # one slot's (K, D) row
+    ops = [m.groups() for m in map(_HLO_OP.match,
+                                   compiled.as_text().splitlines()) if m]
     shape_of = {name: tuple(int(n) for n in dims.split(",") if n)
                 for name, dims, _, _ in ops}
     moves = []
@@ -160,3 +187,38 @@ def test_paged_serve_step_keeps_the_pool_in_place(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < math.prod(stack[1:]) * 2  # < one layer
+
+
+def test_paged_moonlight_step_keeps_the_pool_in_place(one_chip,
+                                                      monkeypatch):
+    """The whole paged Moonlight step at the cell's sizes (1 dense + 6
+    expert layers at published widths, 64 slots x 1024, page 64), jitted
+    as the engine jits it: the latent kernel runs in the dense layer and
+    in the scanned expert layers, and the latent pools stay in place as
+    stablelm's do."""
+    monkeypatch.setattr(flash_decode_module, "on_tpu", lambda: True)
+    cfg = get_config("moonlight_16b_a3b").with_(
+        n_layers=7, dtype="bfloat16", decode_kernel="flash")
+    slots, max_len, page = 64, 1024, 64
+    pages_per_slot, _ = paged_layout(max_len, page, slots)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, param_shapes(model_spec(cfg),
+                                                jnp.bfloat16))
+    caches = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_paged_caches(cfg, slots, max_len, jnp.bfloat16,
+                                  page_size=page)))
+    compiled = jit_paged_step(cfg).lower(
+        params, on_chip(jax.ShapeDtypeStruct((slots, 1), jnp.int32)),
+        caches, on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((slots, pages_per_slot),
+                                     jnp.int32))).compile()
+    text = compiled.as_text()
+    # one call in the lead layer, one in the scan body
+    assert len(re.findall(r"%flash_decode_latent\S* = ", text)) == 2
+    stack = caches["periods"]["0"]["pool_latent"].shape
+    assert stack == (6, slots * pages_per_slot + 1, 1, page, 640)
+    assert caches["lead"]["0"]["pool_latent"].shape == stack[1:]
+    _assert_pool_in_place(compiled, stack, caches)
